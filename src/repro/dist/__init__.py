@@ -20,7 +20,6 @@ the same four ideas become:
 
 See ARCHITECTURE.md for the full paper-mechanism -> module map.
 """
-from repro.dist import _compat as _compat  # installs jax.shard_map shim
 
 __all__ = [
     "act_sharding",

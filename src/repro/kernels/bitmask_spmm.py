@@ -49,18 +49,20 @@ from jax.experimental.pallas import tpu as pltpu
 # the work-list machinery lives in the unified core; these names stay
 # importable from here for the pre-core call sites (tests, conv, autotune)
 from repro.kernels.worklist_core import (  # noqa: F401  (re-exports)
-    DEFAULT_BM, LANE, _CompilerParams, ConvWorkList, WorkList,
-    activation_occupancy, build_worklist, resolve_interpret, worklist_spmm)
+    DEFAULT_BM, LANE, ConvWorkList, WorkList, activation_occupancy,
+    build_worklist, resolve_interpret, tile_dot, worklist_spmm)
 
 
 def subblock_macs(valid, k_safe, occ_ref, m_i, x_ref, w, acc_ref, cnt_ref, *,
-                  two_sided: bool, sub_m: int, bm: int, color=None):
+                  two_sided: bool, sub_m: int, bm: int, kb: int, color=None):
     """MAC one (bm, bk) x (bk, bn) tile into ``acc_ref``.
 
     In two-sided mode the tile is processed as ``bm // sub_m`` row
     sub-blocks, each skipped when its occupancy bit (activation rows all
     zero) is clear — a single live decode lane does not force MACs for the
-    other ``bm - sub_m`` rows of its block. ``cnt_ref`` (optional (1, 1)
+    other ``bm - sub_m`` rows of its block. ``occ_ref`` is the flattened
+    [M // sub_m * kb] occupancy table (:func:`flat_occupancy`; 1-D, so the
+    scalar memory holds it unpadded). ``cnt_ref`` (optional (1,) SMEM
     scratch) counts executed sub-block MACs (tile MACs when one-sided) so
     tests can assert the skip logic fires exactly. Shared with the fused
     FFN kernel (:mod:`repro.kernels.fused_ffn`).
@@ -71,41 +73,57 @@ def subblock_macs(valid, k_safe, occ_ref, m_i, x_ref, w, acc_ref, cnt_ref, *,
     paper's §3.3 coloring, selected dynamically instead of duplicating the
     call per color.
     """
-    def _acc_read(lo, size):
+    def _acc(lo, size):
         if color is None:
-            return acc_ref[lo:lo + size, :]
-        return pl.load(acc_ref, (pl.dslice(color, 1), pl.dslice(lo, size),
-                                 slice(None)))[0]
+            return (slice(lo, lo + size), slice(None))
+        return (color, slice(lo, lo + size), slice(None))
 
-    def _acc_write(lo, size, v):
-        if color is None:
-            acc_ref[lo:lo + size, :] = v
-        else:
-            pl.store(acc_ref, (pl.dslice(color, 1), pl.dslice(lo, size),
-                               slice(None)), v[None])
+    def _count():
+        if cnt_ref is not None:
+            cnt_ref[0] += 1
 
     if not two_sided:
         @pl.when(valid)
         def _mac():
-            _acc_write(0, bm, _acc_read(0, bm) + jnp.dot(
-                x_ref[...].astype(jnp.float32), w,
-                preferred_element_type=jnp.float32))
-            if cnt_ref is not None:
-                cnt_ref[0, 0] = cnt_ref[0, 0] + 1
+            acc_ref[_acc(0, bm)] += tile_dot(x_ref[...], w)
+            _count()
         return
     nsub = bm // sub_m
     base = m_i * nsub
     for si in range(nsub):
-        live = jnp.logical_and(valid, occ_ref[base + si, k_safe] > 0)
+        live = jnp.logical_and(valid, occ_ref[(base + si) * kb + k_safe] > 0)
 
         @pl.when(live)
         def _mac(si=si):
             lo = si * sub_m
-            _acc_write(lo, sub_m, _acc_read(lo, sub_m) + jnp.dot(
-                x_ref[lo:lo + sub_m, :].astype(jnp.float32), w,
-                preferred_element_type=jnp.float32))
-            if cnt_ref is not None:
-                cnt_ref[0, 0] = cnt_ref[0, 0] + 1
+            acc_ref[_acc(lo, sub_m)] += tile_dot(x_ref[lo:lo + sub_m, :], w)
+            _count()
+
+
+def flat_occupancy(x: jnp.ndarray, sub_m: int, bk: int) -> jnp.ndarray:
+    """:func:`activation_occupancy` flattened row-major to 1-D — the form
+    the predicated kernels scalar-prefetch (a 2-D table's last dim pads to
+    128 words in SMEM; 1-D does not)."""
+    return activation_occupancy(x, sub_m, bk).reshape(-1)
+
+
+def count_output(nb: int, mb: int):
+    """(out_shape, out_spec) of the executed-MAC counters: one lane-wide
+    (1, 128) row per (n, m) grid cell, so each block spans whole trailing
+    dims. :func:`count_rows` reads the [nb, mb] map back out."""
+    return (jax.ShapeDtypeStruct((nb, mb, 1, LANE), jnp.int32),
+            pl.BlockSpec((None, None, 1, LANE),
+                         lambda n, m, j, *_: (n, m, 0, 0)))
+
+
+def flush_count(cntout_ref, cnt_ref):
+    """Write the cell's SMEM MAC counter into its count-output row."""
+    cntout_ref[...] = jnp.full(cntout_ref.shape, cnt_ref[0], jnp.int32)
+
+
+def count_rows(cnt: jnp.ndarray) -> jnp.ndarray:
+    """[nb, mb, 1, 128] counter rows -> the int32 [nb, mb] map."""
+    return cnt[:, :, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -116,7 +134,7 @@ def subblock_macs(valid, k_safe, occ_ref, m_i, x_ref, w, acc_ref, cnt_ref, *,
 # dense-grid predicated kernel — the instrumented measurement path — and
 # the FFN-shaped work-list variant below.
 def _kernel(idx_ref, occ_ref, x_ref, w_ref, *refs, nsteps: int,
-            two_sided: bool, sub_m: int, bm: int, count_macs: bool):
+            two_sided: bool, sub_m: int, bm: int, kb: int, count_macs: bool):
     if count_macs:
         o_ref, cntout_ref, acc_ref, cnt_ref = refs
     else:
@@ -130,18 +148,18 @@ def _kernel(idx_ref, occ_ref, x_ref, w_ref, *refs, nsteps: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if cnt_ref is not None:
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+            cnt_ref[0] = 0
 
     k_idx = idx_ref[n_i, j]
     subblock_macs(k_idx >= 0, jnp.maximum(k_idx, 0), occ_ref, m_i, x_ref,
-                  w_ref[0, 0].astype(jnp.float32), acc_ref, cnt_ref,
-                  two_sided=two_sided, sub_m=sub_m, bm=bm)
+                  w_ref[0, 0], acc_ref, cnt_ref, two_sided=two_sided,
+                  sub_m=sub_m, bm=bm, kb=kb)
 
     @pl.when(j == nsteps - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
         if cntout_ref is not None:
-            cntout_ref[...] = cnt_ref[...]
+            flush_count(cntout_ref, cnt_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bn", "bm", "sub_m",
@@ -171,19 +189,20 @@ def bitmask_spmm(x: jnp.ndarray, indices: jnp.ndarray, vals: jnp.ndarray,
     mb = M // bm
 
     # activation-side sub-block occupancy (two-sided mode); tiny O(MK) pass
-    occ = activation_occupancy(x, sub_m, bk)
+    occ = flat_occupancy(x, sub_m, bk)
 
     grid = (nb, mb, max_nz)
     kernel = functools.partial(_kernel, nsteps=max_nz, two_sided=two_sided,
-                               sub_m=sub_m, bm=bm, count_macs=count_macs)
+                               sub_m=sub_m, bm=bm, kb=K // bk,
+                               count_macs=count_macs)
     out_shape = jax.ShapeDtypeStruct((M, N), x.dtype)
     out_specs = pl.BlockSpec((bm, bn), lambda n, m, j, idx, occ_: (m, n))
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     if count_macs:
-        out_shape = [out_shape, jax.ShapeDtypeStruct((nb, mb), jnp.int32)]
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, 1), lambda n, m, j, idx, occ_: (n, m))]
-        scratch.append(pltpu.VMEM((1, 1), jnp.int32))
+        cnt_shape, cnt_spec = count_output(nb, mb)
+        out_shape = [out_shape, cnt_shape]
+        out_specs = [out_specs, cnt_spec]
+        scratch.append(pltpu.SMEM((1,), jnp.int32))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -201,9 +220,11 @@ def bitmask_spmm(x: jnp.ndarray, indices: jnp.ndarray, vals: jnp.ndarray,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(indices, occ, x, vals)
+    if count_macs:
+        return out[0], count_rows(out[1])
     return out
 
 

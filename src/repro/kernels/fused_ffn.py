@@ -26,15 +26,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bitmask_spmm import subblock_macs
+from repro.kernels.bitmask_spmm import flat_occupancy, subblock_macs
 from repro.kernels.worklist_core import (  # noqa: F401  (re-exports)
-    ACTS, DEFAULT_BM, GATED_ACTS, LANE, WorkList, _CompilerParams,
-    activation_occupancy, resolve_interpret, worklist_spmm)
+    ACTS, DEFAULT_BM, GATED_ACTS, LANE, WorkList, activation_occupancy,
+    resolve_interpret, worklist_spmm)
 from repro.kernels.worklist_core import activate as _activate
 
 
 def _kernel(*args, nsteps: int, act: str, two_sided: bool, sub_m: int,
-            bm: int, gated: bool):
+            bm: int, kb: int, gated: bool):
     if gated:
         (in_idx_ref, g_idx_ref, occ_ref, x_in_ref, w_in_ref, x_g_ref,
          w_g_ref, o_ref, acc_h_ref, acc_g_ref) = args
@@ -53,13 +53,13 @@ def _kernel(*args, nsteps: int, act: str, two_sided: bool, sub_m: int,
 
     k_in = in_idx_ref[n_i, j]
     subblock_macs(k_in >= 0, jnp.maximum(k_in, 0), occ_ref, m_i, x_in_ref,
-                  w_in_ref[0, 0].astype(jnp.float32), acc_h_ref, None,
-                  two_sided=two_sided, sub_m=sub_m, bm=bm)
+                  w_in_ref[0, 0], acc_h_ref, None, two_sided=two_sided,
+                  sub_m=sub_m, bm=bm, kb=kb)
     if gated:
         k_g = g_idx_ref[n_i, j]
         subblock_macs(k_g >= 0, jnp.maximum(k_g, 0), occ_ref, m_i, x_g_ref,
-                      w_g_ref[0, 0].astype(jnp.float32), acc_g_ref, None,
-                      two_sided=two_sided, sub_m=sub_m, bm=bm)
+                      w_g_ref[0, 0], acc_g_ref, None, two_sided=two_sided,
+                      sub_m=sub_m, bm=bm, kb=kb)
 
     @pl.when(j == nsteps - 1)
     def _flush():
@@ -95,7 +95,7 @@ def fused_ffn_spmm(x: jnp.ndarray, in_idx: jnp.ndarray, in_vals: jnp.ndarray,
     assert bm % sub_m == 0, (bm, sub_m)
     mb = M // bm
 
-    occ = activation_occupancy(x, sub_m, bk)
+    occ = flat_occupancy(x, sub_m, bk)
 
     if gated:
         # align the two chunk lists on one j axis (pad with -1 / zero tiles)
@@ -116,7 +116,7 @@ def fused_ffn_spmm(x: jnp.ndarray, in_idx: jnp.ndarray, in_vals: jnp.ndarray,
     grid = (nb, mb, mnz)
     kernel = functools.partial(_kernel, nsteps=mnz, act=act,
                                two_sided=two_sided, sub_m=sub_m, bm=bm,
-                               gated=gated)
+                               kb=K // bk, gated=gated)
     x_spec_in = pl.BlockSpec(
         (bm, bk), (lambda n, m, j, i_idx, g_idx, occ_:
                    (m, jnp.maximum(i_idx[n, j], 0))) if gated else
@@ -158,7 +158,7 @@ def fused_ffn_spmm(x: jnp.ndarray, in_idx: jnp.ndarray, in_vals: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((M, nb * bn), x.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(*scalars, *operands)
 

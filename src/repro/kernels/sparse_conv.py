@@ -64,16 +64,20 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bitmask as bm
 from repro.core.sparse import Padding, Stride, normalize_padding, \
     normalize_stride
-from repro.kernels.bitmask_spmm import subblock_macs
+from repro.kernels.bitmask_spmm import (count_output, count_rows,
+                                       flat_occupancy, flush_count,
+                                       subblock_macs)
 from repro.kernels.worklist_core import (  # noqa: F401  (re-exports)
-    DEFAULT_BM, LANE, _CompilerParams, ConvWorkList, activation_occupancy,
-    build_worklist, on_tpu, resolve_executor, resolve_interpret,
-    schedule_counters, segment_spmm, worklist_spmm)
+    DEFAULT_BM, LANE, ConvWorkList, activation_occupancy, batched_tile_dot,
+    build_worklist, occupancy_out_shape, occupancy_rows, on_tpu,
+    resolve_executor, resolve_interpret, schedule_counters, segment_spmm,
+    tile_occupancy, worklist_spmm)
 
 
 def _conv_kernel(idx_ref, occ_ref, x_ref, w_ref, *refs, nsteps: int,
-                 two_sided: bool, sub_m: int, bm_rows: int, mb_per_img: int,
-                 fuse_relu: bool, emit_occupancy: bool, count_macs: bool):
+                 two_sided: bool, sub_m: int, bm_rows: int, kb: int,
+                 mb_per_img: int, fuse_relu: bool, emit_occupancy: bool,
+                 count_macs: bool):
     refs = list(refs)
     o_ref = refs.pop(0)
     occ_out_ref = refs.pop(0) if emit_occupancy else None
@@ -89,33 +93,29 @@ def _conv_kernel(idx_ref, occ_ref, x_ref, w_ref, *refs, nsteps: int,
 
     @pl.when(j == 0)
     def _init():
-        pl.store(acc_ref, (pl.dslice(parity, 1), slice(None), slice(None)),
-                 jnp.zeros((1,) + acc_ref.shape[1:], acc_ref.dtype))
+        acc_ref[parity] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
         if cnt_ref is not None:
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+            cnt_ref[0] = 0
 
     k_idx = idx_ref[n_i, j]
     # MAC into the accumulator of this image's color (single call — the
     # color is a dynamic index, not a predicated pair of calls)
     subblock_macs(k_idx >= 0, jnp.maximum(k_idx, 0), occ_ref, m_i, x_ref,
-                  w_ref[0, 0].astype(jnp.float32), acc_ref, cnt_ref,
-                  two_sided=two_sided, sub_m=sub_m, bm=bm_rows, color=parity)
+                  w_ref[0, 0], acc_ref, cnt_ref, two_sided=two_sided,
+                  sub_m=sub_m, bm=bm_rows, kb=kb, color=parity)
 
     @pl.when(j == nsteps - 1)
     def _flush():
-        y = pl.load(acc_ref, (pl.dslice(parity, 1), slice(None),
-                              slice(None)))[0]
+        y = acc_ref[parity]
         if fuse_relu:
             y = jnp.maximum(y, 0.0)
         o_ref[...] = y.astype(o_ref.dtype)
         if occ_out_ref is not None:
             # next layer's activation tile bitmask: sub_m-row occupancy of
             # the post-epilogue output tile, one column per n block
-            nsub = bm_rows // sub_m
-            occ_out_ref[...] = (y.reshape(nsub, sub_m, -1) != 0).any(
-                axis=(1, 2)).astype(jnp.int32).reshape(nsub, 1)
+            occ_out_ref[...] = tile_occupancy(y, sub_m)
         if cntout_ref is not None:
-            cntout_ref[...] = cnt_ref[...]
+            flush_count(cntout_ref, cnt_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "bn", "bm_rows", "sub_m",
@@ -157,28 +157,29 @@ def sparse_conv_spmm(patches: jnp.ndarray, indices: jnp.ndarray,
     assert bm_rows % sub_m == 0, (bm_rows, sub_m)
     assert mb % mb_per_img == 0, (mb, mb_per_img)
 
-    occ = activation_occupancy(patches, sub_m, bk)
+    occ = flat_occupancy(patches, sub_m, bk)
 
     grid = (nb, mb, max_nz)
     kernel = functools.partial(
         _conv_kernel, nsteps=max_nz, two_sided=two_sided, sub_m=sub_m,
-        bm_rows=bm_rows, mb_per_img=mb_per_img, fuse_relu=fuse_relu,
-        emit_occupancy=emit_occupancy, count_macs=count_macs)
+        bm_rows=bm_rows, kb=K // bk, mb_per_img=mb_per_img,
+        fuse_relu=fuse_relu, emit_occupancy=emit_occupancy,
+        count_macs=count_macs)
 
     out_shape = [jax.ShapeDtypeStruct((M, N), patches.dtype)]
     out_specs = [pl.BlockSpec((bm_rows, bn), lambda n, m, j, idx, occ_: (m, n))]
     if emit_occupancy:
         nsub = bm_rows // sub_m
-        out_shape.append(jax.ShapeDtypeStruct((M // sub_m, nb), jnp.int32))
-        out_specs.append(pl.BlockSpec((nsub, 1),
-                                      lambda n, m, j, idx, occ_: (m, n)))
+        out_shape.append(occupancy_out_shape(nb, mb, nsub))
+        out_specs.append(pl.BlockSpec((None, None, nsub, 1),
+                                      lambda n, m, j, idx, occ_: (n, m, 0, 0)))
     if count_macs:
-        out_shape.append(jax.ShapeDtypeStruct((nb, mb), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1),
-                                      lambda n, m, j, idx, occ_: (n, m)))
+        cnt_shape, cnt_spec = count_output(nb, mb)
+        out_shape.append(cnt_shape)
+        out_specs.append(cnt_spec)
     scratch = [pltpu.VMEM((2, bm_rows, bn), jnp.float32)]  # §3.3 colors
     if count_macs:
-        scratch.append(pltpu.VMEM((1, 1), jnp.int32))
+        scratch.append(pltpu.SMEM((1,), jnp.int32))
 
     out = pl.pallas_call(
         kernel,
@@ -197,9 +198,14 @@ def sparse_conv_spmm(patches: jnp.ndarray, indices: jnp.ndarray,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(indices, occ, patches, vals)
+    out = list(out)
+    if emit_occupancy:
+        out[1] = occupancy_rows(out[1])
+    if count_macs:
+        out[-1] = count_rows(out[-1])
     return tuple(out)
 
 
@@ -228,10 +234,7 @@ def _worklist_spmm_xla_slabs(slabs, vals, wl_slot, wl_m, wl_n, wl_j, *, bn,
     x4 = slabs.reshape(L, mb, bm_rows, bk)
     xg = x4[wl_slot, wl_m]                        # [T, bm, bk]
     wg = vals[wl_n, wl_j]                         # [T, bk, bn]
-    prod = jax.lax.dot_general(
-        xg.astype(jnp.float32), wg.astype(jnp.float32),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)       # [T, bm, bn]
+    prod = batched_tile_dot(xg, wg)               # [T, bm, bn]
     return segment_spmm(prod, wl_n * mb + wl_m, nb=nb, mb=mb,
                         bm_rows=bm_rows, bn=bn, M=M, out_dtype=slabs.dtype,
                         act="relu" if fuse_relu else None, sub_m=sub_m,
@@ -314,6 +317,9 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
     * ``"patches"`` — ``jax.lax.conv_general_dilated_patches``;
       channel-major feature order (cin, kh, kw), matching the
       ``w.transpose(2, 0, 1, 3)`` matrixization of the packing path.
+      It is a one-hot convolution, run at ``HIGHEST`` precision so the
+      TPU copies fp32 pixels exactly instead of rounding them through a
+      reduced-precision MXU pass.
     * ``"slices"``  — kh*kw strided slices of the padded map, stacked and
       transposed to the same channel-major order; XLA:CPU fuses this ~2x
       better than the patches primitive.
@@ -331,7 +337,8 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
         pad = normalize_padding(padding)
         patches = jax.lax.conv_general_dilated_patches(
             x, (kh, kw), (sh, sw), pad,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)
         b, oh, ow, f = patches.shape
         return patches.reshape(b, oh * ow, f), (oh, ow)
     if strategy not in ("slices", "taps"):

@@ -59,10 +59,6 @@ LANE = 128
 # dist-vision regression gate holds the committed bench to it.
 SHARD_BALANCE_TOL = 0.10
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 GATED_ACTS = ("swiglu", "geglu")
 ACTS = ("relu", "relu2", "gelu") + GATED_ACTS
 
@@ -619,6 +615,48 @@ def schedule_counters(wl: WorkList, *,
 # ---------------------------------------------------------------------------
 # the Pallas walker (grid = the flat work list)
 # ---------------------------------------------------------------------------
+def tile_dot(x, w):
+    """One fp32 (bm, bk) x (bk, bn) tile MAC, shared by every Pallas kernel.
+    ``HIGHEST`` keeps the MXU at full fp32 on TPU (its default is a
+    reduced-precision pass); on CPU an fp32 dot is fp32 either way."""
+    return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def batched_tile_dot(xg, wg):
+    """The XLA executors' [T, bm, bk] x [T, bk, bn] batched tile GEMM, at
+    the same fp32 precision as :func:`tile_dot`."""
+    return jax.lax.dot_general(
+        xg.astype(jnp.float32), wg.astype(jnp.float32),
+        (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def tile_occupancy(y, sub_m: int):
+    """In-kernel occupancy of one (bm, bn) output tile: an int32 (bm //
+    sub_m, 1) column, 1 where a ``sub_m``-row sub-block holds a non-zero.
+    Every intermediate stays 2-D or 3-D with kept dims, which is what the
+    TPU's kernel compiler lays out (a 1-D reduction result is not)."""
+    bm, bn = y.shape
+    nz = (y != 0).astype(jnp.int32).reshape(bm // sub_m, sub_m, bn)
+    return jnp.max(jnp.max(nz, axis=1), axis=1, keepdims=True)
+
+
+def occupancy_out_shape(nb: int, mb: int, nsub: int):
+    """The kernels' emitted occupancy array: one (nsub, 1) block per (n, m)
+    output tile, whole trailing dims so every block is layout-legal."""
+    return jax.ShapeDtypeStruct((nb, mb, nsub, 1), jnp.int32)
+
+
+def occupancy_rows(occ):
+    """[nb, mb, nsub, 1] emitted blocks -> the [M // sub_m, nb] occupancy
+    map every caller reads (row sub-block major, one column per n block)."""
+    nb, mb, nsub, _ = occ.shape
+    return occ[..., 0].transpose(1, 2, 0).reshape(mb * nsub, nb)
+
+
 def _walk_kernel(*args, streams: int, ncolors: int, mb_per_img: int,
                  sub_m: int, bm_rows: int, act: Optional[str],
                  emit_occupancy: bool):
@@ -640,47 +678,30 @@ def _walk_kernel(*args, streams: int, ncolors: int, mb_per_img: int,
     t = pl.program_id(0)
     parity = (m_ref[t] // mb_per_img) % ncolors
 
-    def _load(ref):
-        return pl.load(ref, (pl.dslice(parity, 1), slice(None),
-                             slice(None)))[0]
-
-    def _store(ref, v):
-        pl.store(ref, (pl.dslice(parity, 1), slice(None), slice(None)),
-                 v[None])
-
     @pl.when(first_ref[t] == 1)
     def _init():
-        _store(acc_ref, jnp.zeros(acc_ref.shape[1:], acc_ref.dtype))
+        acc_ref[parity] = jnp.zeros(acc_ref.shape[1:], acc_ref.dtype)
         if acc2_ref is not None:
-            _store(acc2_ref, jnp.zeros(acc2_ref.shape[1:], acc2_ref.dtype))
+            acc2_ref[parity] = jnp.zeros(acc2_ref.shape[1:], acc2_ref.dtype)
 
     @pl.when(k_ref[t] >= 0)
     def _mac():
         # a scheduled step is a live chunk by construction: one dense MXU
         # tile MAC, nothing left to predicate in-lane
-        _store(acc_ref, _load(acc_ref) + jnp.dot(
-            x_ref[...].astype(jnp.float32), w_ref[0, 0].astype(jnp.float32),
-            preferred_element_type=jnp.float32))
+        acc_ref[parity] += tile_dot(x_ref[...], w_ref[0, 0])
 
     if streams == 2:
         @pl.when(k2_ref[t] >= 0)
         def _mac2():
-            _store(acc2_ref, _load(acc2_ref) + jnp.dot(
-                x2_ref[...].astype(jnp.float32),
-                w2_ref[0, 0].astype(jnp.float32),
-                preferred_element_type=jnp.float32))
+            acc2_ref[parity] += tile_dot(x2_ref[...], w2_ref[0, 0])
 
     @pl.when(last_ref[t] == 1)
     def _flush():
-        g = _load(acc2_ref) if acc2_ref is not None else None
-        y = activate(_load(acc_ref), g, act)
+        g = acc2_ref[parity] if acc2_ref is not None else None
+        y = activate(acc_ref[parity], g, act)
         o_ref[...] = y.astype(o_ref.dtype)
         if occ_out_ref is not None:
-            # next layer's activation tile bitmask: sub_m-row occupancy of
-            # the post-epilogue output tile, one column per n block
-            nsub = bm_rows // sub_m
-            occ_out_ref[...] = (y.reshape(nsub, sub_m, -1) != 0).any(
-                axis=(1, 2)).astype(jnp.int32).reshape(nsub, 1)
+            occ_out_ref[...] = tile_occupancy(y, sub_m)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -719,9 +740,10 @@ def _worklist_spmm_pallas(patches, vals, vals2, *wl_args, streams, bk, bn,
                               (m[t], n[t]))]
     if emit_occupancy:
         nsub = bm_rows // sub_m
-        out_shape.append(jax.ShapeDtypeStruct((M // sub_m, nb), jnp.int32))
+        out_shape.append(occupancy_out_shape(nb, M // bm_rows, nsub))
         out_specs.append(pl.BlockSpec(
-            (nsub, 1), lambda t, n, m, k, j, f, l, *rest: (m[t], n[t])))
+            (None, None, nsub, 1),
+            lambda t, n, m, k, j, f, l, *rest: (n[t], m[t], 0, 0)))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -733,9 +755,11 @@ def _worklist_spmm_pallas(patches, vals, vals2, *wl_args, streams, bk, bn,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(*wl_args, *operands)
+    if emit_occupancy:
+        return out[0], occupancy_rows(out[1])
     return tuple(out)
 
 
@@ -778,10 +802,7 @@ def _gather_dot(patches, vals, wl_m, wl_k, wl_n, wl_j, *, bk, bm_rows, mb):
     x4 = patches.reshape(mb, bm_rows, kb, bk)
     xg = x4[wl_m, :, wl_k, :]                     # [T, bm, bk]
     wg = vals[wl_n, wl_j]                         # [T, bk, bn]
-    return jax.lax.dot_general(
-        xg.astype(jnp.float32), wg.astype(jnp.float32),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)       # [T, bm, bn]
+    return batched_tile_dot(xg, wg)               # [T, bm, bn]
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -12,6 +12,13 @@ import math
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    """Auto axes: the sharding-constraint and gather code here is written
+    for them (``jax.make_mesh`` defaults to Explicit)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -29,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False,
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
     # single-pod mesh on a 512-device host: use the first pod's devices
     assert len(devices) >= n, (len(devices), n)
     return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), axes)
@@ -37,4 +44,5 @@ def make_production_mesh(*, multi_pod: bool = False,
 
 def make_debug_mesh(model: int = 1, data: int = 1):
     """Tiny mesh for CPU smoke runs (1 real device)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

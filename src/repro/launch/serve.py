@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import load_config, load_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import Request, Scheduler, generate
 from repro.sparsity.sparse_ffn import sparsify_model
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--density", type=float, default=0.35,
                     help="pruning density for --sparse")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = load_smoke(args.arch) if args.smoke else load_config(args.arch)
     key = jax.random.PRNGKey(args.seed)

@@ -21,10 +21,12 @@ TPU performance; the structural numbers are what carries.
 from __future__ import annotations
 
 import argparse
+from typing import Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.vision import (ImageRequest, VisionEngine, autotune_model,
                           build_vision_model, layer_table,
                           measured_densities, oracle_check)
@@ -54,7 +56,7 @@ def blob_images(rng: np.random.Generator, n: int, size: int,
     return imgs
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", default="VGGNet",
                     choices=["AlexNet", "VGGNet", "ResNet18", "ResNet50"])
@@ -82,7 +84,8 @@ def main() -> None:
                     help="data-shard the engine batch over an N-device "
                          "mesh (N must divide --slots; bitwise identical "
                          "to solo)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = None
     if args.mesh is not None:

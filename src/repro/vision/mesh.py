@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import repro.dist  # noqa: F401  (installs the jax.shard_map compat shim)
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.collective_matmul import (exchange_overlap_fraction,

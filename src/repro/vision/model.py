@@ -373,12 +373,15 @@ def forward(model: VisionModel, x: jnp.ndarray, *, sub_m: int = 8,
 
 def dense_forward(model: VisionModel, x: jnp.ndarray) -> jnp.ndarray:
     """Oracle: the same pruned (chain-folded) filters through
-    ``jax.lax.conv_general_dilated`` + ReLU + pooling."""
+    ``jax.lax.conv_general_dilated`` + ReLU + pooling, at ``HIGHEST``
+    precision so it is a float32 reference on every backend (the TPU's
+    default conv precision is a reduced-precision MXU pass)."""
     for layer in model.layers:
         w = jnp.asarray(layer.conv.w_dense)
         x = jax.lax.conv_general_dilated(
             x, w, layer.stride, layer.padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=jax.lax.Precision.HIGHEST)
         x = jnp.maximum(x, 0.0)
         if layer.pool_after is not None:
             x = max_pool(x, *layer.pool_after)

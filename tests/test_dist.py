@@ -66,7 +66,7 @@ DIST_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs.base import load_smoke, ShapeConfig
 from repro.data.pipeline import batch_for
 from repro.dist import partitioning as part
@@ -77,7 +77,7 @@ from repro.train.train_step import make_train_step
 
 # 1. sharded end-to-end train step == single-device train step
 cfg = load_smoke("qwen3_4b")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 shape = ShapeConfig("t", 32, 4, "train")
 batch = batch_for(cfg, shape, 0)
 params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -105,7 +105,7 @@ print("SHARDED_TRAIN_OK", d)
 
 # 2. collective matmul matches oracle under shard_map
 rng = np.random.default_rng(0)
-mesh1 = jax.make_mesh((8,), ("model",))
+mesh1 = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 x = rng.normal(size=(16, 64)).astype(np.float32)
 w = rng.normal(size=(64, 32)).astype(np.float32)
 fn = jax.shard_map(lambda a, b: cm.allgather_matmul(a, b, "model"),
@@ -122,7 +122,7 @@ print("COLLECTIVE_MATMUL_OK")
 
 # 3. hierarchical compressed psum ~= exact mean
 from repro.dist.compression import hierarchical_psum
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 g = np.arange(8, dtype=np.float32).reshape(8, 1) * np.ones((8, 16),
                                                            np.float32)
 def hp(gl):

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import load_smoke
 from repro.dist import partitioning as part
@@ -126,7 +126,8 @@ def test_constrain_residual_noop_without_context():
 
 
 def test_constrain_residual_applies_under_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     spec = sp_spec(mesh)
     assert spec == P(("data",), ("model",), None)
     with act_sharding(mesh, spec):
@@ -143,7 +144,8 @@ def test_sp_forward_numerics_unchanged():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 1, cfg.vocab,
                               dtype=jnp.int32)
     ref, _ = M.forward(params, toks, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh, act_sharding(mesh, sp_spec(mesh)):
         got, _ = jax.jit(lambda p, t: M.forward(p, t, cfg))(params, toks)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
