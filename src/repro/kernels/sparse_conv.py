@@ -455,8 +455,9 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
                                padding)
         flat = None
     else:
-        patches, (oh, ow) = extract_patches(x, kh, kw, stride, padding,
-                                            strategy=im2col)
+        with jax.named_scope("im2col"):
+            patches, (oh, ow) = extract_patches(x, kh, kw, stride, padding,
+                                                strategy=im2col)
     m_img = oh * ow
     k_total = w.shape[0]
     pad_rows = (-m_img) % bm_rows
@@ -464,8 +465,9 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
     if not lazy:
         pad_k = k_total - patches.shape[-1]
         assert pad_k >= 0, (patches.shape, k_total)
-        patches = jnp.pad(patches, ((0, 0), (0, pad_rows), (0, pad_k)))
-        flat = patches.reshape(b * m_pad, k_total)
+        with jax.named_scope("im2col"):
+            patches = jnp.pad(patches, ((0, 0), (0, pad_rows), (0, pad_k)))
+            flat = patches.reshape(b * m_pad, k_total)
     mb = (b * m_pad) // bm_rows
     aux = {"m_img": m_img, "k_total": k_total, "oh": oh, "ow": ow}
 
@@ -524,40 +526,43 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
             else:
                 aux["schedule"]["static_scheduled_steps"] = wl.num_steps
 
-    if lazy:
-        live = wl.k >= 0
-        union = np.unique(wl.k[live])
-        if union.size == 0:
-            M = b * m_pad
-            out0 = jnp.zeros((M, w.n_blocks * w.bn), x.dtype)
-            res = (out0,) + ((jnp.zeros((M // sub_m, w.n_blocks),
-                                        jnp.int32),) if emit_occupancy
-                             else ())
+    with jax.named_scope("walker"):
+        if lazy:
+            live = wl.k >= 0
+            union = np.unique(wl.k[live])
+            if union.size == 0:
+                M = b * m_pad
+                out0 = jnp.zeros((M, w.n_blocks * w.bn), x.dtype)
+                res = (out0,) + ((jnp.zeros((M // sub_m, w.n_blocks),
+                                            jnp.int32),) if emit_occupancy
+                                 else ())
+            else:
+                slot_of = np.zeros(k_total // w.bk, np.int32)
+                slot_of[union] = np.arange(union.size, dtype=np.int32)
+                with jax.named_scope("im2col"):
+                    slabs = extract_tap_slabs(x, kh, kw, stride, padding,
+                                              chunks=union, bk=w.bk,
+                                              m_pad=m_pad)
+                res = _worklist_spmm_xla_slabs(
+                    slabs, w.vals, jnp.asarray(slot_of[wl.k[live]]),
+                    jnp.asarray(wl.m[live]), jnp.asarray(wl.n[live]),
+                    jnp.asarray(wl.j[live]), bn=w.bn, bm_rows=bm_rows,
+                    sub_m=sub_m, nb=wl.nb, mb=mb, fuse_relu=fuse_relu,
+                    emit_occupancy=emit_occupancy)
+        elif schedule == "compact":
+            res = sparse_conv_spmm_wl(
+                flat, w.vals, wl, bk=w.bk, bn=w.bn, bm_rows=bm_rows,
+                sub_m=sub_m, mb_per_img=m_pad // bm_rows, fuse_relu=fuse_relu,
+                emit_occupancy=emit_occupancy, interpret=interpret,
+                executor=executor)
+        elif schedule == "dense":
+            res = sparse_conv_spmm(
+                flat, w.indices, w.vals, bk=w.bk, bn=w.bn, bm_rows=bm_rows,
+                sub_m=sub_m, mb_per_img=m_pad // bm_rows, two_sided=two_sided,
+                fuse_relu=fuse_relu, emit_occupancy=emit_occupancy,
+                interpret=interpret, count_macs=count_macs)
         else:
-            slot_of = np.zeros(k_total // w.bk, np.int32)
-            slot_of[union] = np.arange(union.size, dtype=np.int32)
-            slabs = extract_tap_slabs(x, kh, kw, stride, padding,
-                                      chunks=union, bk=w.bk, m_pad=m_pad)
-            res = _worklist_spmm_xla_slabs(
-                slabs, w.vals, jnp.asarray(slot_of[wl.k[live]]),
-                jnp.asarray(wl.m[live]), jnp.asarray(wl.n[live]),
-                jnp.asarray(wl.j[live]), bn=w.bn, bm_rows=bm_rows,
-                sub_m=sub_m, nb=wl.nb, mb=mb, fuse_relu=fuse_relu,
-                emit_occupancy=emit_occupancy)
-    elif schedule == "compact":
-        res = sparse_conv_spmm_wl(
-            flat, w.vals, wl, bk=w.bk, bn=w.bn, bm_rows=bm_rows, sub_m=sub_m,
-            mb_per_img=m_pad // bm_rows, fuse_relu=fuse_relu,
-            emit_occupancy=emit_occupancy, interpret=interpret,
-            executor=executor)
-    elif schedule == "dense":
-        res = sparse_conv_spmm(
-            flat, w.indices, w.vals, bk=w.bk, bn=w.bn, bm_rows=bm_rows,
-            sub_m=sub_m, mb_per_img=m_pad // bm_rows, two_sided=two_sided,
-            fuse_relu=fuse_relu, emit_occupancy=emit_occupancy,
-            interpret=interpret, count_macs=count_macs)
-    else:
-        raise ValueError(f"unknown schedule {schedule!r}")
+            raise ValueError(f"unknown schedule {schedule!r}")
     out = res[0].reshape(b, m_pad, w.n_blocks * w.bn)
     out = out[:, :m_img, :cout].reshape(b, oh, ow, cout)
     i = 1

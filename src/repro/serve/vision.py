@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.balance import round_robin_permutation
 from repro.vision import model as VM
 from repro.vision.engine import ImageRequest
@@ -181,10 +182,11 @@ class VisionServer:
                  mesh=None):
         if verify_artifacts:
             from repro.analysis import raise_on_errors, verify_model
-            raise_on_errors(
-                verify_model(model, f"serve/{model.name}",
-                             check_values=False),
-                "VisionServer admission")
+            with obs.span("serve.verify"):
+                raise_on_errors(
+                    verify_model(model, f"serve/{model.name}",
+                                 check_values=False),
+                    "VisionServer admission")
         if not buckets:
             raise ValueError("need at least one shape bucket")
         self.model = model
@@ -330,18 +332,27 @@ class VisionServer:
         if bucket in self._warm:
             return
         shape = (self.num_slots, bucket, bucket, self._channels)
-        t0 = time.monotonic()
-        self._fwd(jnp.zeros(shape, np.float32)).block_until_ready()
-        self.stats.compile_s += time.monotonic() - t0
-        if bucket not in self._est:
-            t1 = time.monotonic()
+        with obs.span("serve.warmup", bucket=bucket):
+            t0 = time.monotonic()
             self._fwd(jnp.zeros(shape, np.float32)).block_until_ready()
-            self._est[bucket] = max(time.monotonic() - t1, 1e-9)
+            self.stats.compile_s += time.monotonic() - t0
+            if bucket not in self._est:
+                t1 = time.monotonic()
+                self._fwd(jnp.zeros(shape, np.float32)).block_until_ready()
+                self._est[bucket] = max(time.monotonic() - t1, 1e-9)
         self._warm.add(bucket)
 
     def step(self) -> bool:
         """One engine event: admit the selected bucket batch and run it,
-        or idle forward to the next arrival. Returns False when drained."""
+        or idle forward to the next arrival. Returns False when drained.
+
+        With :mod:`repro.obs` recording, an admitted batch is a span
+        ``serve.step`` with the children ``serve.admit`` (selection and
+        batch assembly), ``serve.h2d`` (copy in), ``serve.dispatch`` (the
+        forward's call returning), ``serve.wait`` (the device finishing),
+        ``serve.d2h`` (copy out) and ``serve.record``; the step's
+        attribute ``images`` is the number of requests it answers."""
+        t_admit = time.time_ns()
         now = self.clock.now()
         sel = self._select_batch(now)
         if sel is None:
@@ -349,20 +360,38 @@ class VisionServer:
                 return False
             self.clock.sleep_until(min(p.arrival_s for p in self.queue))
             return True
-        bucket, batch_reqs = sel
-        self._warm_bucket(bucket)
-        batch = np.zeros((self.num_slots, bucket, bucket, self._channels),
-                         np.float32)
-        # §3.3.2 round-robin lane assignment (spread across lanes, don't
-        # pin lane 0)
-        lanes = round_robin_permutation(self.num_slots,
-                                        self._rr_lane)[:len(batch_reqs)]
-        self._rr_lane += len(batch_reqs)
-        for lane, p in zip(lanes, batch_reqs):
-            batch[lane] = p.image
+        with obs.span("serve.step", start_ns=t_admit, images=len(sel[1])):
+            self._run_batch(*sel, t_admit)
+        return True
+
+    def _run_batch(self, bucket: int, batch_reqs: List[_Pending],
+                   t_admit: int) -> None:
+        with obs.span("serve.admit", start_ns=t_admit):
+            self._warm_bucket(bucket)
+            batch = np.zeros((self.num_slots, bucket, bucket,
+                              self._channels), np.float32)
+            # §3.3.2 round-robin lane assignment (spread across lanes,
+            # don't pin lane 0)
+            lanes = round_robin_permutation(self.num_slots,
+                                            self._rr_lane)[:len(batch_reqs)]
+            self._rr_lane += len(batch_reqs)
+            for lane, p in zip(lanes, batch_reqs):
+                batch[lane] = p.image
         t0 = time.monotonic()
-        out = np.asarray(self._fwd(jnp.asarray(batch)))
+        with obs.span("serve.h2d"):
+            x = jnp.asarray(batch)
+        with obs.span("serve.dispatch"):
+            out = self._fwd(x)
+        with obs.span("serve.wait"):
+            out.block_until_ready()
+        with obs.span("serve.d2h"):
+            out = np.asarray(out)
         measured = time.monotonic() - t0
+        with obs.span("serve.record"):
+            self._record_batch(bucket, batch_reqs, lanes, out, measured)
+
+    def _record_batch(self, bucket: int, batch_reqs: List[_Pending],
+                      lanes, out: np.ndarray, measured: float) -> None:
         if self._fixed_cost is not None and getattr(
                 self.clock, "virtual", False):
             self.clock.advance(self._fixed_cost[bucket])
@@ -389,7 +418,6 @@ class VisionServer:
                 self.stats.deadlined += 1
                 if rec.missed:
                     self.stats.sla_misses += 1
-        return True
 
     def run(self, requests: Optional[List[ImageRequest]] = None
             ) -> Dict[int, np.ndarray]:

@@ -29,6 +29,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import balance, bitmask as bm
 from repro.core.sparse import prune_by_magnitude
 from repro.kernels.worklist_core import (SHARD_BALANCE_TOL, shard_imbalance,
@@ -233,6 +234,7 @@ class PackedConv:
         return 1.0 - self.chunk_density()
 
 
+@obs.spanned("setup.pack")
 def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
                        num_shards: int = 16, chunk: int = bm.CHUNK,
                        balance_filters: bool = True,

@@ -17,7 +17,6 @@ topology does not linearize into a chain and stays simulator-only.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -211,21 +210,28 @@ def _forward_layers(model: VisionModel, x: jnp.ndarray, *, sub_m: int,
     ``use_tuned`` applies each layer's cached autotune winner
     (``conv.tuned``, from :func:`repro.kernels.autotune.autotune_model`) —
     per-layer ``bm_rows`` / ``sub_m`` / im2col strategy instead of the
-    global knobs; layers without a record keep the globals."""
-    for layer in model.layers:
+    global knobs; layers without a record keep the globals.
+
+    Each layer runs under the name scope ``layer<NN>``, its parts under
+    ``im2col``, ``walker`` and ``pool`` (op metadata only: the compiled
+    program and its instruction names are unchanged), so a device trace
+    attributes every operation to its layer."""
+    for i, layer in enumerate(model.layers):
         c = layer.conv
         cfg = c.tuned.config if (use_tuned and c.tuned is not None) else None
-        x, _ = sparse_conv2d_nhwc(
-            x, c.packed, c.kh, c.kw, c.cout, stride=layer.stride,
-            padding=layer.padding,
-            sub_m=cfg.sub_m if cfg else sub_m,
-            bm_rows=cfg.bm_rows if cfg else DEFAULT_BM,
-            im2col=cfg.im2col if cfg else im2col,
-            two_sided=two_sided,
-            fuse_relu=True, interpret=interpret, schedule=schedule,
-            executor=executor, layout=c.layout, wl_cache=c.wl_cache)
-        if layer.pool_after is not None:
-            x = max_pool(x, *layer.pool_after)
+        with jax.named_scope(f"layer{i:02d}"):
+            x, _ = sparse_conv2d_nhwc(
+                x, c.packed, c.kh, c.kw, c.cout, stride=layer.stride,
+                padding=layer.padding,
+                sub_m=cfg.sub_m if cfg else sub_m,
+                bm_rows=cfg.bm_rows if cfg else DEFAULT_BM,
+                im2col=cfg.im2col if cfg else im2col,
+                two_sided=two_sided,
+                fuse_relu=True, interpret=interpret, schedule=schedule,
+                executor=executor, layout=c.layout, wl_cache=c.wl_cache)
+            if layer.pool_after is not None:
+                with jax.named_scope("pool"):
+                    x = max_pool(x, *layer.pool_after)
     return x
 
 
@@ -266,15 +272,17 @@ def compile_forward(model: VisionModel, *, sub_m: int = 8,
            use_tuned, tuned_key, mesh_key)
     fn = model._fwd_cache.get(key)
     if fn is None:
-        body = functools.partial(
-            _forward_layers, model, sub_m=sub_m, two_sided=two_sided,
-            schedule=schedule, executor=executor, im2col=im2col,
-            interpret=interpret, use_tuned=use_tuned)
+        def vision_forward(x):       # the compiled module's name
+            return _forward_layers(
+                model, x, sub_m=sub_m, two_sided=two_sided,
+                schedule=schedule, executor=executor, im2col=im2col,
+                interpret=interpret, use_tuned=use_tuned)
         if mesh is not None:
             from repro.vision.mesh import shard_forward
-            fn = shard_forward(body, mesh, donate=donate)
+            fn = shard_forward(vision_forward, mesh, donate=donate)
         else:
-            fn = jax.jit(body, donate_argnums=(0,) if donate else ())
+            fn = jax.jit(vision_forward,
+                         donate_argnums=(0,) if donate else ())
         model._fwd_cache[key] = fn
     return fn
 

@@ -156,3 +156,28 @@ def test_vgg16_forward_compiles_at_224(topo, vgg16, devices):
                           interpret=False, mesh=mesh)
     compiled = fwd.lower(_sds((8, 224, 224, 3), sharding)).compile()
     assert _mosaic_calls(compiled) == vgg16.num_layers == 13
+
+
+def test_vgg16_forward_keeps_kernel_names_under_layer_scopes(one_chip,
+                                                             vgg16):
+    """The per-layer name scopes are metadata only: the compiled v5e
+    forward is still the module ``jit_vision_forward``, its Mosaic calls
+    are still the instructions ``_worklist_spmm_pallas.N``, and each
+    carries its layer's ``walker`` scope."""
+    import re
+    from repro.vision import compile_forward
+    fwd = compile_forward(vgg16, executor="pallas", im2col="patches",
+                          interpret=False)
+    text = fwd.lower(_sds((8, 224, 224, 3), one_chip)).compile().as_text()
+    assert text.startswith("HloModule jit_vision_forward")
+    calls = [l for l in text.splitlines() if MOSAIC in l]
+    assert len(calls) == vgg16.num_layers
+    layers = []
+    for line in calls:
+        assert re.match(r"\s*(ROOT )?%_worklist_spmm_pallas\.\d+ = ", line)
+        m = re.search(r'op_name="jit\(vision_forward\)/(layer\d\d)/walker/',
+                      line)
+        assert m, line[:200]
+        layers.append(m.group(1))
+    assert sorted(layers) == [f"layer{i:02d}"
+                              for i in range(vgg16.num_layers)]
