@@ -306,9 +306,13 @@ def conv_out_size(H: int, W: int, kh: int, kw: int, stride: Stride,
 
 
 def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
-                    padding: Padding, *, strategy: str = "auto"
+                    padding: Padding, *, strategy: str = "auto",
+                    m_pad: Optional[int] = None
                     ) -> Tuple[jnp.ndarray, Tuple[int, int]]:
-    """im2col rows for the implicit GEMM: [B, OH*OW, Cin*kh*kw] (+ (OH, OW)).
+    """im2col rows for the implicit GEMM: [B, M, Cin*kh*kw] (+ (OH, OW)).
+
+    ``M`` is ``OH*OW``, or ``m_pad`` when given: each image's rows then
+    end in zero rows up to ``m_pad`` (whole walker row blocks).
 
     All strategies are pure jax ops, so patch extraction fuses into
     whatever jit the caller runs under — the K-fold patch blow-up never
@@ -320,38 +324,60 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
       It is a one-hot convolution, run at ``HIGHEST`` precision so the
       TPU copies fp32 pixels exactly instead of rounding them through a
       reduced-precision MXU pass.
-    * ``"slices"``  — kh*kw strided slices of the padded map, stacked and
-      transposed to the same channel-major order; XLA:CPU fuses this ~2x
-      better than the patches primitive.
-    * ``"taps"``    — the same slices *without* the transpose: tap-major
-      feature order (kh, kw, cin), matching ``layout="tap"`` packing
-      (``w.reshape(kh*kw*cin, cout)``) — cheaper still, since the
-      channel-major shuffle never materializes.
+    * ``"slices"``  — kh*kw strided slices of the padded map, stacked
+      into the same channel-major order; XLA:CPU fuses this ~2x better
+      than the patches primitive.
+    * ``"taps"``    — the same slices concatenated on the channel axis:
+      tap-major feature order (kh, kw, cin), matching ``layout="tap"``
+      packing (``w.reshape(kh*kw*cin, cout)``). Each slice is one block
+      copy into its columns of the patch matrix: on the TPU the matrix is
+      written once, with no one-hot convolution and no relayout copy.
+      Where ``m_pad`` adds rows, each slice is padded as it is copied,
+      which on the TPU replaces a separate pass over the whole matrix.
+      :func:`~repro.sparsity.conv.build_sparse_chain` packs tap-major
+      every layer whose ``cin`` fills whole chunks, so those layers take
+      this path.
     * ``"auto"``    — patches on TPU, slices elsewhere (resolved at trace
-      time, like the interpret/executor knobs).
+      time, like the interpret/executor knobs); for channel layouts only,
+      since :func:`sparse_conv2d_nhwc` resolves a tap layer to ``taps``.
     """
     if strategy == "auto":
         strategy = "patches" if on_tpu() else "slices"
+    b, cin = x.shape[0], x.shape[3]
     if strategy == "patches":
         sh, sw = normalize_stride(stride)
         pad = normalize_padding(padding)
-        patches = jax.lax.conv_general_dilated_patches(
+        p = jax.lax.conv_general_dilated_patches(
             x, (kh, kw), (sh, sw), pad,
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             precision=jax.lax.Precision.HIGHEST)
-        b, oh, ow, f = patches.shape
-        return patches.reshape(b, oh * ow, f), (oh, ow)
-    if strategy not in ("slices", "taps"):
+        oh, ow = p.shape[1:3]
+    elif strategy in ("slices", "taps"):
+        xp, oh, ow, sh, sw = _padded_input(x, kh, kw, stride, padding)
+        parts = [xp[:, dy:dy + (oh - 1) * sh + 1:sh,
+                    dx:dx + (ow - 1) * sw + 1:sw, :]
+                 for dy in range(kh) for dx in range(kw)]
+    else:
         raise ValueError(f"unknown im2col strategy {strategy!r}")
-    b, H, W, cin = x.shape
-    xp, oh, ow, sh, sw = _padded_input(x, kh, kw, stride, padding)
-    parts = [xp[:, dy:dy + (oh - 1) * sh + 1:sh,
-                dx:dx + (ow - 1) * sw + 1:sw, :]
-             for dy in range(kh) for dx in range(kw)]
-    p = jnp.stack(parts, axis=3)                  # [b, oh, ow, kh*kw, cin]
+    m_img = oh * ow
+    rows = (0, 0) if m_pad is None else (0, m_pad - m_img)
+    if strategy == "taps":
+        if rows[1]:
+            # each slice lands in its columns already row-padded
+            p = jnp.concatenate(
+                [jnp.pad(s.reshape(b, m_img, cin), ((0, 0), rows, (0, 0)))
+                 for s in parts], axis=-1)        # [b, m_pad, kh*kw*cin]
+        else:
+            # the barrier keeps XLA from sinking the reshape below the
+            # concatenate, which would copy every strided slice out of the
+            # map before copying it into the matrix
+            p = jax.lax.optimization_barrier(
+                jnp.concatenate(parts, axis=-1))  # [b, oh, ow, kh*kw*cin]
+        return p.reshape(b, m_img + rows[1], kh * kw * cin), (oh, ow)
     if strategy == "slices":
-        p = p.transpose(0, 1, 2, 4, 3)            # channel-major features
-    return p.reshape(b, oh * ow, cin * kh * kw), (oh, ow)
+        p = jnp.stack(parts, axis=-1)             # [b, oh, ow, cin, kh*kw]
+    p = p.reshape(b, m_img, cin * kh * kw)
+    return jnp.pad(p, ((0, 0), rows, (0, 0))), (oh, ow)
 
 
 def extract_tap_slabs(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
@@ -450,23 +476,18 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
                  or resolve_executor(executor) != "xla"):
         im2col, lazy = "taps", False
     b = x.shape[0]
-    if lazy:
-        oh, ow = conv_out_size(x.shape[1], x.shape[2], kh, kw, stride,
-                               padding)
-        flat = None
-    else:
-        with jax.named_scope("im2col"):
-            patches, (oh, ow) = extract_patches(x, kh, kw, stride, padding,
-                                                strategy=im2col)
+    oh, ow = conv_out_size(x.shape[1], x.shape[2], kh, kw, stride, padding)
     m_img = oh * ow
     k_total = w.shape[0]
-    pad_rows = (-m_img) % bm_rows
-    m_pad = m_img + pad_rows
+    m_pad = m_img + (-m_img) % bm_rows
+    flat = None
     if not lazy:
-        pad_k = k_total - patches.shape[-1]
-        assert pad_k >= 0, (patches.shape, k_total)
         with jax.named_scope("im2col"):
-            patches = jnp.pad(patches, ((0, 0), (0, pad_rows), (0, pad_k)))
+            patches, _ = extract_patches(x, kh, kw, stride, padding,
+                                         strategy=im2col, m_pad=m_pad)
+            pad_k = k_total - patches.shape[-1]
+            assert pad_k >= 0, (patches.shape, k_total)
+            patches = jnp.pad(patches, ((0, 0), (0, 0), (0, pad_k)))
             flat = patches.reshape(b * m_pad, k_total)
     mb = (b * m_pad) // bm_rows
     aux = {"m_img": m_img, "k_total": k_total, "oh": oh, "ow": ow}

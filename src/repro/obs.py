@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import functools
 import time
 from typing import Deque, List, Optional
 
@@ -76,17 +75,6 @@ def span(name: str, start_ns: Optional[int] = None, **attrs):
         return _NOOP
     return _Span(_record(name, time.time_ns() if start_ns is None
                          else start_ns, 0, attrs))
-
-
-def spanned(name: str):
-    """Decorator: each call of the function is a span ``name``."""
-    def wrap(fn):
-        @functools.wraps(fn)
-        def call(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
-        return call
-    return wrap
 
 
 def _on_time_span(event: str, start: float, end: float, **kw) -> None:

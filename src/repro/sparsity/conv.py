@@ -6,14 +6,17 @@ tensors; the paper's accelerator linearizes them through the same matrix
 interface (im2col), so the conv path adds exactly two conv-specific steps
 and reuses everything else:
 
-* **matrixization** — two layouts. ``layout="channel"`` (the unstructured
-  default) is ``w.transpose(2, 0, 1, 3).reshape(Cin*kh*kw, Cout)``,
-  matching ``conv_general_dilated_patches`` feature order.
-  ``layout="tap"`` (the chunk-aligned pattern) is the plain
-  ``w.reshape(kh*kw*Cin, Cout)`` — K index = tap * Cin + channel — so a
-  K-chunk lies inside one filter tap and a live chunk maps to one
-  shifted-slab slice of the input (the lazy im2col path). Both are
-  chunk-padded for the BlockSpec grid.
+* **matrixization** — two layouts, chosen from the filter's shape.
+  ``layout="tap"`` is the plain ``w.reshape(kh*kw*Cin, Cout)`` — K index
+  = tap * Cin + channel — so a K-chunk lies inside one filter tap and a
+  live chunk maps to one shifted-slab slice of the input. Every layer
+  whose ``Cin`` is a whole number of chunks takes it: its patch matrix is
+  then a block copy of the ``kh*kw`` shifted slices (no relayout), and
+  the lazy im2col path can materialize single chunks. ``layout="channel"``
+  is ``w.transpose(2, 0, 1, 3).reshape(Cin*kh*kw, Cout)``, matching
+  ``conv_general_dilated_patches`` feature order; narrower layers (a
+  3-channel stem, 64-channel VGG blocks) keep it, since a tap chunk would
+  straddle taps there. Both are chunk-padded for the BlockSpec grid.
 * **chain folding** — greedy-balancing layer *i*'s output channels permutes
   the feature map's channel axis; the repair is folding the inverse into
   layer *i+1*'s **input-channel** axis (axis 2 of the 4-D filter), which is
@@ -44,8 +47,12 @@ def matrixize_filters(w: np.ndarray, chunk: int = bm.CHUNK,
 
     ``layout="channel"`` uses channel-major feature order (the
     ``conv_general_dilated_patches`` layout); ``layout="tap"`` keeps the
-    tensor's natural tap-major order (K = tap * Cin + c). K pads to
-    ``bk`` blocks and N to ``bn`` blocks (both default to ``chunk``).
+    tensor's natural tap-major order (K = tap * Cin + c) and needs
+    ``Cin % bk == 0``, so each K-chunk lies inside one tap. The two are
+    the same matrix with its K rows permuted. :func:`build_sparse_chain`
+    picks ``tap`` for every layer that allows it (:func:`chain_layout`).
+    K pads to ``bk`` blocks and N to ``bn`` blocks (both default to
+    ``chunk``).
     """
     kh, kw, cin, cout = w.shape
     bk = chunk if bk is None else bk
@@ -76,6 +83,19 @@ def pack_conv_filters(w: np.ndarray, chunk: int = bm.CHUNK,
     return bm.block_sparsify(
         matrixize_filters(w, chunk, layout, bk=bk, bn=bn), bk=bk, bn=bn,
         pad_to=pad_to)
+
+
+def chain_layout(shape: Tuple[int, int, int, int], pattern: str,
+                 chunk: int = bm.CHUNK) -> Tuple[str, int, int]:
+    """(layout, bk, bn) of one [kh, kw, Cin, Cout] layer of a chain.
+
+    The chunk pattern picks its own (:func:`structured.
+    choose_chunk_layout`). Unstructured layers are tap-major when ``Cin``
+    fills whole chunks, channel-major otherwise; both at ``bk = bn =
+    chunk``. The choice rests on the shape alone."""
+    if pattern == "chunk":
+        return structured.choose_chunk_layout(shape, chunk)
+    return ("tap" if shape[2] % chunk == 0 else "channel"), chunk, chunk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,8 +198,8 @@ class PackedConv:
     §3.2 compaction, computed once per (layer, batch geometry).
 
     ``layout``/``pattern`` record how the filters were matrixized and
-    pruned (``"channel"``+``"unstructured"`` is the legacy path); ``tuned``
-    holds the autotuner's winning per-layer tile config
+    pruned (the layout follows from the shape, :func:`chain_layout`);
+    ``tuned`` holds the autotuner's winning per-layer tile config
     (:class:`repro.kernels.autotune.TuneRecord`) when
     :func:`repro.kernels.autotune.autotune_conv` has run, and
     ``compile_forward`` bakes it into the whole-net jit."""
@@ -234,7 +254,6 @@ class PackedConv:
         return 1.0 - self.chunk_density()
 
 
-@obs.spanned("setup.pack")
 def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
                        num_shards: int = 16, chunk: int = bm.CHUNK,
                        balance_filters: bool = True,
@@ -252,8 +271,12 @@ def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
     :class:`~repro.analysis.diagnostics.AnalysisError` on any invariant
     violation — the pack-time gate for untrusted checkpoints.
 
-    ``pattern="unstructured"`` (default) is the legacy path: per-filter
-    magnitude pruning, per-channel greedy balance, channel-major packing.
+    ``pattern="unstructured"`` (default): per-filter magnitude pruning and
+    per-channel greedy balance. A layer whose ``Cin`` is a whole number of
+    chunks packs tap-major, so its im2col rows are a block copy of the
+    ``kh*kw`` shifted slices of the map; narrower layers pack
+    channel-major (:func:`chain_layout`). Pruning and balance act per
+    output filter, so the layout only permutes the packed K rows.
     ``pattern="chunk"`` prunes at (bk, bn) tile granularity in the
     tap-major layout (:mod:`repro.sparsity.structured`) so the packed
     chunk maps have real dead chunks; balancing then moves whole banks
@@ -274,19 +297,40 @@ def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
     never permuted (its contiguous assignment is recorded as-is), and a
     cout that is not whole ``bn`` blocks keeps the contiguous split (a
     partial block cannot move without breaking the packed padding).
+
+    The whole build is the span ``setup.pack`` (:mod:`repro.obs`); its
+    attribute ``tap_layers`` counts the layers packed tap-major.
     """
     if pattern not in ("unstructured", "chunk"):
         raise ValueError(f"unknown pattern {pattern!r}")
     ws = [np.asarray(w, np.float32) for w in weights]
     for a, b_ in zip(ws, ws[1:]):
         assert a.shape[3] == b_.shape[2], (a.shape, b_.shape)
+    plans = [chain_layout(w.shape, pattern, chunk) for w in ws]
+    with obs.span("setup.pack",
+                  tap_layers=sum(p[0] == "tap" for p in plans)):
+        out = _pack_chain(ws, plans, density=density, num_shards=num_shards,
+                          chunk=chunk, balance_filters=balance_filters,
+                          pattern=pattern, micro_ranges=micro_ranges,
+                          mesh_devices=mesh_devices)
+        if strict:
+            # local import: repro.analysis imports this module
+            from repro.analysis import raise_on_errors, verify_chain
+            raise_on_errors(verify_chain(out), "build_sparse_chain")
+    return out
+
+
+def _pack_chain(ws: List[np.ndarray], plans: List[Tuple[str, int, int]], *,
+                density: float, num_shards: int, chunk: int,
+                balance_filters: bool, pattern: str, micro_ranges: int,
+                mesh_devices: Optional[int]) -> List[PackedConv]:
+    """The per-layer loop of :func:`build_sparse_chain`; ``ws`` is
+    modified in place as each balance permutation folds forward."""
     out: List[PackedConv] = []
     for i, w in enumerate(ws):
         last = i == len(ws) - 1
-        layout, bk, bn = ("channel", chunk, chunk)
+        layout, bk, bn = plans[i]
         info = None
-        if pattern == "chunk":
-            layout, bk, bn = structured.choose_chunk_layout(w.shape, chunk)
         if density < 1.0:
             if pattern == "chunk" and layout == "tap":
                 w, info = structured.prune_chunk_aligned(
@@ -350,8 +394,4 @@ def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
                               else ("unstructured" if pattern == "chunk"
                                     else pattern),
                               prune_info=info, shard=shard))
-    if strict:
-        # local import: repro.analysis imports this module
-        from repro.analysis import raise_on_errors, verify_chain
-        raise_on_errors(verify_chain(out), "build_sparse_chain")
     return out

@@ -181,6 +181,17 @@ def test_packing_is_a_span(recording):
     assert names.count("setup.pack") == 1
 
 
+@pytest.mark.parametrize("arch, tap_layers", [("VGGNet", 10),
+                                               ("AlexNet", 3)])
+def test_packing_span_counts_tap_major_layers(recording, arch, tap_layers):
+    """``tap_layers``: the layers whose input channels fill whole chunks
+    (VGG16 conv3_1 on, AlexNet conv3-5) are packed tap-major."""
+    model = build_vision_model(arch, seed=0)
+    pack, = [s for s in obs.snapshot()["spans"] if s["name"] == "setup.pack"]
+    assert pack["attrs"] == {"tap_layers": tap_layers}
+    assert sum(l.conv.layout == "tap" for l in model.layers) == tap_layers
+
+
 def test_forward_carries_layer_scopes_in_its_metadata():
     model = build_vision_model("VGGNet", num_layers=3, seed=0)
     assert model.layers[1].pool_after is not None

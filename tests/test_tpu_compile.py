@@ -141,6 +141,36 @@ def test_predicated_ffn_kernels_compile_at_qwen3_4b_width(one_chip, kernel):
     assert _mosaic_calls(compiled) == 1
 
 
+def test_tap_layer_im2col_is_block_copies_at_vgg16_conv3_1(one_chip):
+    """A tap-major layer's im2col (conv3_1: 112 px, cin 128, batch 8) is
+    block copies into the patch matrix: no one-hot convolution, and no
+    copy or transpose of the patch tensor before the walker reads it."""
+    import math
+    import re
+    from repro.kernels.sparse_conv import sparse_conv2d_nhwc
+    from repro.sparsity.conv import build_sparse_chain
+    rng = np.random.default_rng(0)
+    conv, = build_sparse_chain(
+        [rng.normal(size=(3, 3, 128, 128)).astype(np.float32)],
+        density=0.334)
+    assert conv.layout == "tap"
+    fn = jax.jit(lambda x: sparse_conv2d_nhwc(
+        x, conv.packed, 3, 3, 128, executor="pallas", interpret=False,
+        layout=conv.layout)[0])
+    compiled = fn.lower(_sds((8, 112, 112, 128), one_chip)).compile()
+    assert _mosaic_calls(compiled) == 1
+    text = compiled.as_text()
+    ops = [(math.prod(int(d) for d in dims.split(",") if d), op)
+           for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* (\S+)\(",
+                                      text[text.index("\nENTRY"):])]
+    patch_elems = 8 * 112 * 112 * 9 * 128
+    assert (patch_elems, "fusion") in ops
+    assert "convolution" not in {op for _, op in ops}
+    relayouts = [op for n, op in ops if n == patch_elems
+                 and op in ("copy", "copy-start", "transpose")]
+    assert not relayouts, relayouts
+
+
 @pytest.mark.parametrize("devices", [1, 4], ids=["one-chip", "data-mesh-4"])
 def test_vgg16_forward_compiles_at_224(topo, vgg16, devices):
     """The whole-net jit at batch 8: one Mosaic walker per conv layer,

@@ -288,6 +288,88 @@ def test_im2col_strategies_bitwise_equal(rng):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("stride, padding",
+                         [(1, "SAME"), ((2, 1), "VALID"),
+                          ((1, 2), ((1, 0), (2, 1)))])
+def test_taps_are_patches_in_tap_major_order(rng, stride, padding):
+    """``taps`` holds the values of ``patches``, bit for bit, with the
+    columns moved from channel-major (c * 9 + t) to tap-major (t * cin +
+    c) order: the K-row permutation between the two packings."""
+    cin = 5
+    x = rng.normal(size=(2, 11, 9, cin)).astype(np.float32)
+    a, hw = extract_patches(jnp.asarray(x), 3, 3, stride, padding,
+                            strategy="patches")
+    b, hw2 = extract_patches(jnp.asarray(x), 3, 3, stride, padding,
+                             strategy="taps")
+    assert hw == hw2
+    to_channel = (np.arange(cin)[None, :] * 9
+                  + np.arange(9)[:, None]).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(b),
+                                  np.asarray(a)[..., to_channel])
+
+
+# VGG16's conv2_1 -> conv3_1 widths: 64 input channels (half a chunk, so
+# channel-major), then 128 and 128 (whole chunks, so tap-major)
+VGG_WIDTHS = [(3, 3, 64, 128), (3, 3, 128, 128), (3, 3, 128, 256)]
+
+
+@pytest.fixture(scope="module")
+def vgg_width_chain():
+    rng = np.random.default_rng(7)
+    ws = [(rng.normal(size=s) * np.sqrt(2.0 / (9 * s[2])))
+          .astype(np.float32) for s in VGG_WIDTHS]
+    return build_sparse_chain(ws, density=0.334, strict=True)
+
+
+def test_unstructured_chain_is_tap_major_where_cin_fills_chunks(
+        vgg_width_chain):
+    """Tap-major exactly where ``cin % chunk == 0``; the packed matrix is
+    the channel-major one with its K rows permuted, and the verifier
+    (``strict=True``, and ``deep`` here) accepts the chain."""
+    from repro.analysis import has_errors, verify_chain
+    from repro.sparsity.conv import matrixize_filters
+    assert [c.layout for c in vgg_width_chain] == ["channel", "tap", "tap"]
+    assert {c.pattern for c in vgg_width_chain} == {"unstructured"}
+    assert not has_errors(verify_chain(vgg_width_chain, deep=True))
+    for c in vgg_width_chain[1:]:
+        tap = matrixize_filters(c.w_dense, layout="tap")
+        chan = matrixize_filters(c.w_dense, layout="channel")
+        to_channel = (np.arange(c.cin)[None, :] * 9
+                      + np.arange(9)[:, None]).reshape(-1)
+        np.testing.assert_array_equal(tap, chan[to_channel])
+
+
+@pytest.mark.parametrize("size", [16, 32])
+def test_tap_packing_keeps_the_schedule(vgg_width_chain, size):
+    """Per layer, the tap-major work list schedules exactly the steps the
+    channel-major packing of the same filters would: at density 0.334
+    every 128x128 chunk stays live in either order."""
+    from repro.kernels.worklist_core import schedule_counters
+    mb_per_img = -(-size * size // 128)
+    for c in vgg_width_chain:
+        chan = pack_conv_filters(c.w_dense, layout="channel")
+        got, want = (schedule_counters(build_worklist(
+            w.host_indices(), 2 * mb_per_img, mb_per_img=mb_per_img))
+            ["scheduled_steps"] for w in (c.packed, chan))
+        assert got == want > 0
+
+
+@pytest.mark.parametrize("executor", ["xla", "pallas"])
+def test_tap_major_chain_forward_matches_dense(vgg_width_chain, executor):
+    from repro.vision.model import VisionLayer, VisionModel
+    layers = [VisionLayer(c, (1, 1), "SAME", (2, 2) if i == 1 else None)
+              for i, c in enumerate(vgg_width_chain)]
+    model = VisionModel("VGGNet", layers, 16, 0.334)
+    rng = np.random.default_rng(3)
+    x = np.abs(rng.normal(size=(2, 16, 16, 64))).astype(np.float32)
+    x[rng.random(x.shape) >= 0.45] = 0.0
+    out = compile_forward(model, executor=executor)(jnp.asarray(x))
+    ref = dense_forward(model, jnp.asarray(x))
+    assert out.shape == ref.shape == (2, 8, 8, 256)
+    rel = float(jnp.abs(out - ref).max()) / (float(jnp.abs(ref).max()) + 1e-9)
+    assert rel < 1e-4
+
+
 def test_compact_activations_rejected_under_jit(rng):
     """The dynamic intersection needs concrete activations; under a trace
     it must raise, not silently fall back."""
