@@ -1,8 +1,8 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Set-up builds the served model from the seed (weights on the device,
-packed by the program's offline chain), makes a ``VisionServer`` with the
-one compiled shape of the configuration's input size and warms it up, and
+packed by the program from its layer table), makes a ``VisionServer`` with
+the one compiled shape of the configuration's input size, warms it up and
 makes the image pool. The window then starts on a fresh ``WallClock`` and
 drives the server through its public ``submit``/``step`` for ``seconds``:
 a closed loop of ``clients`` callers. Answers are taken out of
@@ -76,19 +76,42 @@ def enable_caches() -> str:
     return path
 
 
-def build_model(config: dict, filters: List[np.ndarray], chips: int):
-    """The served model: the program's offline chain (balance, fold, pack)
-    over the pruned filters, laid out as the configuration's layer table."""
+def chain_model(config: dict, filters: List[np.ndarray], *,
+                mesh_devices: Optional[int] = None):
+    """The program's offline chain (balance, fold, pack) over the pruned
+    filters, one ``VisionLayer`` per entry of the layer table. The chain
+    folds each entry's balance permutation into the next entry's input
+    channels and runs every entry on the one before it, so an entry that
+    needs the graph is refused, never served wrong: ValueError naming the
+    entry and the key."""
     from repro.sparsity.conv import build_sparse_chain
     from repro.vision.model import VisionLayer, VisionModel
+    table = spec.layer_graph(config)
+    for l in table:
+        for key, served in (
+                ("input", l.input == l.index - 1), ("add", l.add is None),
+                ("relu", l.relu),
+                ("pool_after", not l.pool or l.pool[2] == "VALID")):
+            if not served:
+                raise ValueError(f"layer table {l.label}: the program's "
+                                 f"conv chain cannot serve its {key!r}")
     chain = build_sparse_chain(filters, density=1.0,
                                pattern=config["pattern"],
-                               mesh_devices=chips if chips > 1 else None)
-    layers = [VisionLayer(conv, (l["stride"], l["stride"]), l["padding"],
-                          tuple(l["pool_after"]) if l["pool_after"] else None)
-              for conv, l in zip(chain, config["layers"])]
+                               mesh_devices=mesh_devices)
+    layers = [VisionLayer(conv, (l.stride, l.stride), l.padding,
+                          l.pool[:2] if l.pool else None)
+              for conv, l in zip(chain, table)]
     return VisionModel(config["program_arch"], layers, config["input_size"],
                        config["filter_density"])
+
+
+def build_model(config: dict, filters: List[np.ndarray], chips: int):
+    """The served model of the configuration's layer table: the program's
+    own ``repro.vision.model.model_from_table(table, filters, *,
+    mesh_devices)`` where the program has one, else :func:`chain_model`."""
+    from repro.vision import model as program_model
+    build = getattr(program_model, "model_from_table", chain_model)
+    return build(config, filters, mesh_devices=chips if chips > 1 else None)
 
 
 def make_server(cell: spec.Cell, seed: int):
@@ -292,7 +315,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                       for d in devices)
     win.schedule = server.schedule_counters()
     win.peaks = spec.peaks(devices[0].device_kind) if require_chip else None
-    win.work = work.chain_work(config, config["input_size"],
+    win.work = work.table_work(config, config["input_size"],
                                work.nonzero_counts(filters))
     breakdown = None
     if trace:

@@ -228,7 +228,7 @@ def test_a_program_plane_leaves_the_old_readings_as_they_were():
     import work
     rec = _load(OLD)
     config = spec.load_cell("vgg16_224_closed8").config
-    layers = work.chain_work(config, 224, [9 * 64 * 64] * 13)
+    layers = work.table_work(config, 224, [9 * 64 * 64] * 13)
     before = _readings(rec["trace"], layers)
     rec["trace"]["planes"].append(_program_plane(rec["trace"]))
     after = _readings(rec["trace"], layers)

@@ -60,9 +60,11 @@ def test_configs_name_their_files_and_references(bench):
         assert set(c["reduced"]) == set(config["reduced"])
         assert os.path.isfile(os.path.join(spec.BENCH, "references",
                                            config["reference"] + ".py"))
-        chans = [l["in_channels"] for l in config["layers"]]
-        outs = [l["out_channels"] for l in config["layers"]]
-        assert chans[1:] == outs[:-1]     # a chain
+        graph = spec.layer_graph(config)
+        for l in graph:                   # each entry reads what it names
+            src = config["in_channels"] if l.input == spec.IMAGE else \
+                graph[l.input].cout
+            assert l.cin == src, (c["name"], l.label)
         json.dumps(config)
 
 

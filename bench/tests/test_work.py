@@ -17,7 +17,7 @@ def _dense_nonzero(config):
 
 def test_vgg16_macs_per_image():
     config = _config("vgg16_224")
-    dense = work.chain_work(config, 224, _dense_nonzero(config))
+    dense = work.table_work(config, 224, _dense_nonzero(config))
     assert [l.out_hw for l in dense] == [224, 224, 112, 112, 56, 56, 56,
                                          28, 28, 28, 14, 14, 14]
     total = sum(l.dense_macs for l in dense)
@@ -29,7 +29,7 @@ def test_vgg16_macs_per_image():
     keep = [W.kept_per_filter(f.shape, config["filter_density"]) * f.shape[3]
             for f in filters]
     assert nz == keep                     # exact per-filter pruning
-    pruned = work.chain_work(config, 224, nz)
+    pruned = work.table_work(config, 224, nz)
     macs = sum(l.macs for l in pruned)
     assert macs == pytest.approx(0.334 * total, rel=2e-3)   # 5.13 GMAC
     assert work.flops_per_image(pruned) == 2 * macs
@@ -37,7 +37,7 @@ def test_vgg16_macs_per_image():
 
 def test_alexnet_macs_per_image():
     config = _config("alexnet_227")
-    dense = work.chain_work(config, 227, _dense_nonzero(config))
+    dense = work.table_work(config, 227, _dense_nonzero(config))
     assert [l.out_hw for l in dense] == [55, 27, 13, 13, 13]
     assert [l.in_hw for l in dense] == [227, 27, 13, 13, 13]
     assert sum(l.dense_macs for l in dense) == 1_076_634_144   # 1.08 GMAC

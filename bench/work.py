@@ -1,15 +1,19 @@
-"""The work a conv chain requires, from its shapes: the yardstick for
-``step_mfu`` and ``walker_roofline``.
+"""The work a configuration's layer table requires, from its shapes: the
+yardstick for ``step_mfu`` and ``walker_roofline``.
 
 Counts are those of the pruned network itself, whatever implements it:
 
 * MACs of a layer = output positions x weights (dense), or output
-  positions x nonzero weights (required);
+  positions x nonzero weights (required); FLOPs are 2 x MACs, and a
+  residual add counts no FLOPs;
 * bytes of a layer, float32 = its input map + its nonzero weight values
-  + its output map. The weights are read once per call, the maps once per
-  image.
+  + its output map, and for an entry with ``add`` the added map once more
+  (the add is required work, whatever implements it). The weights are
+  read once per call, the maps once per image.
 
-Nothing here imports the program.
+The table may be a graph (``spec.layer_graph``): each entry reads the map
+of the entry it names, at that entry's size after its pool. Nothing here
+imports the program.
 """
 from __future__ import annotations
 
@@ -18,15 +22,18 @@ from typing import List, Sequence
 
 import numpy as np
 
+import spec
+
 F32 = 4
 
 
-def _out_size(h: int, k: int, stride: int, padding: str) -> int:
+def _out_size(h: int, k: int, stride: int, padding: spec.Pad) -> int:
     if padding == "SAME":
         return -(-h // stride)
     if padding == "VALID":
         return (h - k) // stride + 1
-    raise ValueError(f"unknown padding {padding!r}")
+    (lo, hi), _ = padding
+    return (h + lo + hi - k) // stride + 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +44,7 @@ class LayerWork:
     cout: int
     kernel: int
     nonzero: int                  # nonzero filter weights of the layer
+    add: bool = False             # a map is added to the conv's output
 
     @property
     def positions(self) -> int:
@@ -55,24 +63,30 @@ class LayerWork:
         return 2 * self.macs * images
 
     def bytes(self, images: int) -> int:
-        maps = self.in_hw * self.in_hw * self.cin + self.positions * self.cout
+        maps = self.in_hw * self.in_hw * self.cin \
+            + self.positions * self.cout * (2 if self.add else 1)
         return F32 * (images * maps + self.nonzero)
 
 
-def chain_work(config: dict, size: int,
+def table_work(config: dict, size: int,
                nonzero: Sequence[int]) -> List[LayerWork]:
-    """Per-layer work of the chain at a square input of ``size`` pixels;
-    ``nonzero[i]`` is the count of nonzero weights of layer ``i``."""
+    """Per-entry work of the layer table at a square input of ``size``
+    pixels; ``nonzero[i]`` is the count of nonzero weights of entry ``i``.
+    Raises ValueError where an added map's size differs from the conv's."""
     out: List[LayerWork] = []
-    h = size
-    for layer, nz in zip(config["layers"], nonzero):
-        oh = _out_size(h, layer["kernel"], layer["stride"], layer["padding"])
-        out.append(LayerWork(h, oh, layer["in_channels"],
-                             layer["out_channels"], layer["kernel"], int(nz)))
-        h = oh
-        if layer["pool_after"]:
-            win, s = layer["pool_after"]
-            h = (h - win) // s + 1
+    sizes: List[int] = []         # each entry's output size, after its pool
+    for layer, nz in zip(spec.layer_graph(config), nonzero):
+        h = size if layer.input == spec.IMAGE else sizes[layer.input]
+        oh = _out_size(h, layer.kernel, layer.stride, layer.padding)
+        if layer.add is not None and sizes[layer.add] != oh:
+            raise ValueError(f"layer table {layer.label}: add reads a "
+                             f"{sizes[layer.add]} px map onto {oh} px")
+        out.append(LayerWork(h, oh, layer.cin, layer.cout, layer.kernel,
+                             int(nz), layer.add is not None))
+        if layer.pool:
+            win, s, pad = layer.pool
+            oh = _out_size(oh, win, s, pad)
+        sizes.append(oh)
     return out
 
 
