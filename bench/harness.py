@@ -9,7 +9,10 @@ a closed loop of ``clients`` callers. Answers are taken out of
 ``server.produced`` as they arrive; a seeded sample of them is kept for
 the check. Requests still open at the window's end are served to the end
 (their images do not count as answered in the window). With ``trace`` the
-profiler records the window.
+profiler records the window, and the program's recorder (``repro.obs``)
+records set-up and the window; the run then reads the op scopes of the
+forward that the server ran from its compiled text. An untraced run never
+turns the recorder on.
 """
 from __future__ import annotations
 
@@ -20,12 +23,13 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 import check
 import loadgen
+import programread
 import spec
 import tracered
 import weights as W
@@ -53,6 +57,12 @@ class Window:
     work: Optional[List[work.LayerWork]] = None   # per layer
     peaks: Optional[Dict[str, float]] = None
     trace: Optional[tracered.Reduction] = None
+    # traced runs only: the recorder's snapshot, the window on its clock
+    # (ns), and device seconds per layer scope (``programread.scope_of``;
+    # None for ops outside every layer), summed over the chips
+    program: Optional[dict] = None
+    window_ns: Optional[Tuple[int, int]] = None
+    scope_s: Optional[Dict[Optional[str], float]] = None
 
 
 def require_chips(chips: int) -> List:
@@ -130,6 +140,22 @@ def make_server(cell: spec.Cell, seed: int):
     return server, filters
 
 
+def forward_text(server, cell: spec.Cell) -> str:
+    """The compiled text of the forward that ``server`` runs: the same
+    jitted function (``compile_forward`` keeps it on the model per
+    argument set), lowered for the cell's batch, so its compile comes from
+    the in-memory or the persistent compilation cache."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.ops import on_tpu
+    from repro.vision import compile_forward
+    fwd = compile_forward(server.model, donate=on_tpu(), mesh=server.mesh)
+    size = int(cell.config["input_size"])
+    x = jax.ShapeDtypeStruct((server.num_slots, size, size,
+                              server.model.layers[0].conv.cin), jnp.float32)
+    return fwd.lower(x).compile().as_text()
+
+
 class SpanLog:
     """The harness's own host spans, on the wall clock in nanoseconds.
     They mark the window and the steps in the trace, since the profiler's
@@ -145,6 +171,11 @@ class SpanLog:
             yield
         finally:
             self.spans.append((name, t0, time.time_ns()))
+
+    def window(self) -> Tuple[int, int]:
+        """[start, end] of the measured window, in ns."""
+        return next((t0, t1) for n, t0, t1 in self.spans
+                    if n == tracered.WINDOW_SPAN)
 
     def plane(self, origin_ns: int) -> dict:
         """The spans as a host plane of a compact trace that starts at
@@ -269,7 +300,8 @@ def judge(reading: float, limit: Optional[float], checked: int, k: int,
 
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         t_start: float, require_chip: bool = True,
-        keep_trace: Optional[str] = None, control: bool = False) -> dict:
+        keep_trace: Optional[str] = None, control: bool = False,
+        on_window: Optional[Callable[[Window], None]] = None) -> dict:
     """One run; returns the result line as a dict. Raises NoChip.
 
     ``control`` adds the control of the check, which benchmark runs do
@@ -277,38 +309,48 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     passes put in the program's place, on the same sample;
     ``control_correct``, that reading judged by the predicate and limit
     that decide ``correct``; and ``native_high_max_rel_err``, the same at
-    the backend's own ``Precision.HIGH``."""
+    the backend's own ``Precision.HIGH``. ``on_window`` is handed the
+    run's :class:`Window` once the metric readers could read it."""
     import jax
+    from repro import obs
     devices = require_chips(cell.chips) if require_chip else \
         jax.devices()[:cell.chips]
     enable_caches()
 
     config, traffic = cell.config, cell.traffic
-    server, filters = make_server(cell, seed)
-    rng = np.random.default_rng([seed, 0])
-    pool = loadgen.ImagePool(rng, traffic, config)
-    sample = check.Reservoir(int(config["check_answers"]),
-                             np.random.default_rng([seed, 1]))
     win = Window(seconds=seconds, setup_s=0.0, slots=int(traffic["slots"]),
                  chips=cell.chips)
-
     spans = SpanLog()
     log_dir = None
     if trace:
-        import jax.profiler as prof
-        log_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        opts = prof.ProfileOptions()
-        opts.python_tracer_level = 0
-        # the host tracer records each chunk of the input's layout
-        # transpose (a million events in 5 s of VGG16) and doubles the step
-        opts.host_tracer_level = 0
-        prof.start_trace(log_dir, profiler_options=opts)
-    win.setup_s = time.monotonic() - t_start
-    with CompileCounter() as compiles, spans.gc_spans():
-        drive(server, traffic, pool, seconds, sample, win, spans.span)
-    win.compiles_in_window = compiles.count
-    if trace:
-        jax.profiler.stop_trace()
+        obs.enable()           # set-up's spans and compiles too
+    try:
+        server, filters = make_server(cell, seed)
+        rng = np.random.default_rng([seed, 0])
+        pool = loadgen.ImagePool(rng, traffic, config)
+        sample = check.Reservoir(int(config["check_answers"]),
+                                 np.random.default_rng([seed, 1]))
+        if trace:
+            import jax.profiler as prof
+            log_dir = tempfile.mkdtemp(prefix="bench_trace_")
+            opts = prof.ProfileOptions()
+            opts.python_tracer_level = 0
+            # the host tracer records each chunk of the input's layout
+            # transpose (a million events in 5 s of VGG16) and doubles the
+            # step
+            opts.host_tracer_level = 0
+            prof.start_trace(log_dir, profiler_options=opts)
+        win.setup_s = time.monotonic() - t_start
+        with CompileCounter() as compiles, spans.gc_spans():
+            drive(server, traffic, pool, seconds, sample, win, spans.span)
+        win.compiles_in_window = compiles.count
+        if trace:
+            jax.profiler.stop_trace()
+            win.program = obs.snapshot()
+            win.window_ns = spans.window()
+    finally:
+        if trace:
+            obs.disable()
 
     memory_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use",
                                                         0))
@@ -319,12 +361,15 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                                work.nonzero_counts(filters))
     breakdown = None
     if trace:
+        scopes = programread.op_scopes(forward_text(server, cell))
         raw = tracered.compact(tracered.find_xplane(log_dir))
         shutil.rmtree(log_dir, ignore_errors=True)
-        raw["planes"].append(spans.plane(raw["profile_start_ns"]))
+        origin = raw["profile_start_ns"]
+        raw["planes"] += [spans.plane(origin), obs.plane(origin)]
         if keep_trace:
             _save(raw, keep_trace)
         win.trace = tracered.reduce(raw, [d.id for d in devices])
+        win.scope_s = programread.scope_seconds(win.trace, scopes)
         del raw
         breakdown = {"device_ops": win.trace.top_ops(),
                      "idle_gaps": win.trace.longest_gaps()}
@@ -358,6 +403,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     correct = judge(reading, limit, len(ids), sample.k, win.answered,
                     failed)
 
+    if on_window is not None:
+        on_window(win)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = m.read(win)

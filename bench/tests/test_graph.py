@@ -1,7 +1,8 @@
 """Layer tables that form a graph: the yardstick and the reference follow
 named inputs, residual adds and padded pools; chain tables read exactly
 as the chain-only readers read them; the harness takes a new graph cell
-from data files alone, and the chain builder refuses what it cannot
+from data files alone, serves it through the program's graph builder
+where there is one, and the chain builder refuses what it cannot
 serve."""
 import functools
 import json
@@ -269,14 +270,32 @@ def test_a_graph_cell_loads_from_data_files(tmp_path):
     assert {m.name for m in cell.end_to_end} == {"img_per_s", "setup_s"}
 
 
-def test_the_harness_refuses_a_graph_the_program_cannot_serve(tmp_path):
-    """Until the program builds graphs, a graph cell stops at set-up with
-    the builder's ValueError, before any answer is served."""
+def test_the_harness_refuses_a_graph_the_program_cannot_serve(tmp_path,
+                                                            monkeypatch):
+    """Where the program has no graph builder, a graph cell stops at
+    set-up with the chain builder's ValueError, before any answer is
+    served."""
     from conftest import run_small
+    from repro.vision import model as program_model
+    monkeypatch.delattr(program_model, "model_from_table", raising=False)
     _toy_benchmark(tmp_path)
     cell = spec.load_cell("toy_resnet_closed8", root=str(tmp_path))
     with pytest.raises(ValueError, match=r"entry 0 \('stem'\).*'pool_after'"):
         run_small(cell)
+
+
+def test_the_programs_graph_builder_serves_the_graph_cell(tmp_path):
+    """Where the program builds graphs, the toy graph cell runs through
+    its builder and the check holds it to the reference."""
+    from conftest import run_small
+    from repro.vision import model as program_model
+    if not hasattr(program_model, "model_from_table"):
+        pytest.skip("the program has no model_from_table")
+    _toy_benchmark(tmp_path)
+    cell = spec.load_cell("toy_resnet_closed8", root=str(tmp_path))
+    out = run_small(cell)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["answers_checked"]["value"] == 8
 
 
 def test_a_chain_with_explicit_pads_is_served_correctly():

@@ -1,14 +1,17 @@
 """The readers of the program's spans and of the forward's layer scopes
-(``program_trace.py``): on synthetic snapshots and HLO text with known
-answers, and on traces recorded on a v5e."""
+(``programread.py``) and the metric readers built on them: on synthetic
+snapshots and HLO text with known answers, on a trace recorded on a v5e,
+and in the harness's traced run on the CPU."""
 import gzip
 import json
 import os
+import time
 import types
 
 import pytest
 
-import program_trace as PT
+import harness
+import programread as PT
 import spec
 import tracered
 
@@ -95,6 +98,12 @@ def test_setup_readers_read_nothing_without_a_snapshot(reader):
      "pallas_call", "layer11/walker"),
     ("jit(vision_forward)/layer01/pool/reduce_window_max", "layer01/pool"),
     ("jit(vision_forward)/layer00/reshape", "layer00"),
+    # any name scope under the layer with a primitive after it is a part
+    ("jit(vision_forward)/layer07/add/add", "layer07/add"),
+    ("jit(vision_forward)/layer12/proj/walker/jit(_worklist_spmm_pallas)/"
+     "pallas_call", "layer12/proj"),
+    # a transform's component is no part
+    ("jit(vision_forward)/layer03/jit(_pad)/pad", "layer03"),
     ("jit(vision_forward)/transpose", None),
     (None, None)])
 def test_scope_of(op_name, scope):
@@ -173,12 +182,27 @@ def _reduction():
     return types.SimpleNamespace(devices={0: dev, 1: dev})
 
 
+def _origin_pct(reduction, scopes):
+    """Shares of the device time whose scope came from each origin."""
+    secs, total = {}, 0.0
+    for d in reduction.devices.values():
+        for name, ns in d.op_ns.items():
+            origin = scopes.get(name.split(" ", 1)[0], (None, None))[1]
+            secs[origin] = secs.get(origin, 0.0) + ns
+            total += ns
+    return {o: 100.0 * secs.get(o, 0.0) / total
+            for o in ("own", "called", "consumer")}
+
+
 def test_layer_readers_on_a_synthetic_reduction():
     scopes, red = PT.op_scopes(HLO), _reduction()
+    secs = PT.scope_seconds(red, scopes)
+    assert secs == pytest.approx({"layer00/walker": 8e-3,
+                                  "layer00/im2col": 6e-3,
+                                  "layer01/pool": 1e-3, None: 1e-3})
     # two devices, 4 images: im2col (pad + fusion.2) 3 ms per device
-    assert PT.im2col_ms_per_img(red, scopes, 4) == pytest.approx(
-        2 * 3.0 / 4)
-    lay = PT.layers(red, scopes, 4)
+    assert PT.im2col_ms_per_img(secs, 4) == pytest.approx(2 * 3.0 / 4)
+    lay = PT.layers(secs, 4)
     assert lay["per_layer_ms_per_img"] == {
         "layer00": {"walker": 2.0, "im2col": 1.5, "pool": 0.0,
                     "other": 0.0},
@@ -187,11 +211,79 @@ def test_layer_readers_on_a_synthetic_reduction():
     assert lay["unscoped_pct"] == pytest.approx(100 * 0.5 / 8)
     # pad.6 and the walker scoped by their own metadata, fusion.1 by its
     # computation's, fusion.2 by its consumer's
-    assert lay["scope_origin_pct"] == pytest.approx(
+    assert _origin_pct(red, scopes) == pytest.approx(
         {"own": 100 * 6 / 8, "called": 100 * 0.5 / 8,
          "consumer": 100 * 1 / 8})
-    assert PT.im2col_ms_per_img(None, scopes, 4) is None
-    assert PT.layers(red, {}, 4) is None
+    assert PT.im2col_ms_per_img(None, 4) is None
+    assert PT.im2col_ms_per_img({"layer01/pool": 1.0}, 4) is None
+    assert PT.layers(PT.scope_seconds(red, {}), 4) == {
+        "per_layer_ms_per_img": {}, "unscoped_pct": 100.0}
+    assert PT.layers(None, 4) is None
+
+
+# a residual block as a graph forward could scope it: a projection
+# walked under its own part, layer 01's walker output plus it under
+# ``add``, then the ReLU
+ADD_HLO = """HloModule jit_vision_forward, is_scheduled=true
+
+ENTRY %main.4 (x.1: f32[8]) -> f32[8] {
+  %x.1 = f32[8]{0} parameter(0)
+  %_worklist_spmm_pallas.1 = f32[8]{0} custom-call(%x.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(vision_forward)/layer00/proj/walker/jit(_worklist_spmm_pallas)/pallas_call"}
+  %_worklist_spmm_pallas.2 = f32[8]{0} custom-call(%x.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(vision_forward)/layer01/walker/jit(_worklist_spmm_pallas)/pallas_call"}
+  %add.3 = f32[8]{0} add(%_worklist_spmm_pallas.2, %_worklist_spmm_pallas.1), metadata={op_name="jit(vision_forward)/layer01/add/add"}
+  ROOT %maximum.4 = f32[8]{0} maximum(%add.3, %add.3), metadata={op_name="jit(vision_forward)/layer01/max"}
+}
+"""
+
+ADD_READER = '''"""add_ms_per_img: device time under the layers' ``add`` parts per
+image, with the mean ``serve.admit`` beside it, as a later
+configuration's reader could take them from the window."""
+import programread
+
+
+def read(w):
+    secs = [s for k, s in (w.scope_s or {}).items()
+            if k and k.endswith("/add")]
+    if not secs or w.program is None:
+        return None
+    admit = [s["end_ns"] - s["start_ns"] for s in w.program["spans"]
+             if s["name"] == "serve.admit"]
+    return {"add_ms_per_img": 1e3 * sum(secs) / w.images_in_window,
+            "admit_ns": sum(admit) / len(admit),
+            "host_ms_per_step": programread.host_ms_per_step(
+                w.program, w.window_ns)}
+'''
+
+
+def test_a_new_reader_reads_any_part_and_any_span(tmp_path):
+    """A reader file of its own finds an ``add`` part in ``Window.scope_s``
+    and a program span in ``Window.program``, names that the harness
+    does not know."""
+    scopes = PT.op_scopes(ADD_HLO)
+    assert scopes["add.3"] == ("layer01/add", "own")
+    assert scopes["_worklist_spmm_pallas.1"] == ("layer00/proj", "own")
+    assert scopes["maximum.4"] == ("layer01", "own")
+    dev = types.SimpleNamespace(op_ns={
+        "_worklist_spmm_pallas.1 f32[8]": 1e6,
+        "_worklist_spmm_pallas.2 f32[8]": 3e6, "add.3 f32[8]": 0.5e6,
+        "maximum.4 f32[8]": 0.25e6})
+    win = harness.Window(seconds=1.0, setup_s=1.0, slots=8, chips=1,
+                         images_in_window=8, program=_snapshot(),
+                         window_ns=(1000, 2000))
+    win.scope_s = PT.scope_seconds(types.SimpleNamespace(devices={0: dev}),
+                                   scopes)
+    path = tmp_path / "add_ms_per_img.py"
+    path.write_text(ADD_READER)
+    got = spec._module(str(path), "add_ms_per_img").read(win)
+    assert got["add_ms_per_img"] == pytest.approx(0.5 / 8)
+    assert got["admit_ns"] == 5
+    assert got["host_ms_per_step"] == pytest.approx(30 / 1e6)
+    lay = PT.layers(win.scope_s, 8)["per_layer_ms_per_img"]
+    assert lay == {
+        "layer00": {"add": 0.0, "proj": 1 / 8, "walker": 0.0,
+                    "other": 0.0},
+        "layer01": {"add": pytest.approx(0.5 / 8), "proj": 0.0,
+                    "walker": 3 / 8, "other": pytest.approx(0.25 / 8)}}
 
 
 def _load(path):
@@ -255,35 +347,86 @@ def _window(rec):
     return tracered.window(rec["trace"])
 
 
+# what the trace reader's own functions (``bench/program_trace.py``, before
+# they moved to ``programread``) read on the recorded run
+PARENT = {
+    "host_ms_per_step": 2.7213596666666664,
+    "im2col_ms_per_img": 5.216666541666667,
+    "pack_s": 0.274062152, "warmup_s": 23.944375396,
+    "compile_s": 22.370639975,
+    "setup_phases": {"setup.pack": 0.274062152, "serve.verify": 0.02353978,
+                     "serve.warmup": 23.944375396, "jax.trace": 0.715747328,
+                     "jax.compile": 22.964561408,
+                     "jax.cache_load": 0.593921433,
+                     "compile_proper": 22.370639975},
+    "step_mean_ms": {"serve.step": 80.65244833333333,
+                     "serve.admit": 0.76605,
+                     "serve.h2d": 0.40890366666666667,
+                     "serve.dispatch": 0.21482,
+                     "serve.wait": 77.93108866666667,
+                     "serve.d2h": 1.230273, "serve.record": 0.05488},
+    "layer01": {"walker": 0.8385479166666667, "im2col": 1.4565079583333331,
+                "pool": 0.034024500000000006,
+                "other": 0.07814091666666667},
+    "layer12": {"walker": 0.09269775000000001, "im2col": 0.145108875,
+                "pool": 0.0, "other": 0.0010208749999999999},
+    "scope_origin_pct": {"own": 82.21311658907955, "called": 0.0,
+                         "consumer": 17.786883410920414},
+}
+
+
 def test_recorded_program_readings(program):
     meta, snap = program["meta"], program["program"]
     win = _window(program)
     red = tracered.reduce(program["trace"], [0])
     assert red.window_ns == pytest.approx(meta["window_ns"])
-    scopes, images = program["op_scopes"], meta["images"]
+    secs = PT.scope_seconds(red, program["op_scopes"])
+    images = meta["images"]
     assert PT.images_in_window(snap, win) == images == 24
     assert PT.host_ms_per_step(snap, win) == pytest.approx(
-        meta["host_ms_per_step"], rel=1e-9)
-    assert PT.im2col_ms_per_img(red, scopes, images) == pytest.approx(
-        meta["im2col_ms_per_img"], rel=1e-9)
+        PARENT["host_ms_per_step"], rel=1e-12)
+    assert PT.im2col_ms_per_img(secs, images) == pytest.approx(
+        PARENT["im2col_ms_per_img"], rel=1e-12)
     for reader in ("pack_s", "warmup_s", "compile_s"):
         assert getattr(PT, reader)(snap, win[0]) == pytest.approx(
-            meta[reader], rel=1e-9)
-    assert len(PT.step_phases(snap, win)) == 1 + len(PT.STEP_PHASES)
-    assert PT.step_phases(snap, win)["serve.step"]["n"] == \
-        meta["forward_calls"]
+            PARENT[reader], rel=1e-12)
+    assert PT.setup_phases(snap, win[0]) == pytest.approx(
+        PARENT["setup_phases"], rel=1e-12)
+    phases = PT.step_phases(snap, win)
+    assert {k: v["mean_ms"] for k, v in phases.items()} == pytest.approx(
+        PARENT["step_mean_ms"], rel=1e-12)
+    assert phases["serve.step"]["n"] == meta["forward_calls"]
+    per = PT.layers(secs, images)["per_layer_ms_per_img"]
+    for layer in ("layer01", "layer12"):
+        assert per[layer] == pytest.approx(PARENT[layer], rel=1e-12)
+
+
+def test_recorded_readings_through_the_metric_readers(program):
+    """The benchmark's reader files give what ``programread`` gives, on
+    a window built as the harness builds it."""
+    red = tracered.reduce(program["trace"], [0])
+    w = harness.Window(seconds=red.window_s, setup_s=1.0, slots=8, chips=1,
+                       images_in_window=24, trace=red,
+                       program=program["program"],
+                       window_ns=_window(program),
+                       scope_s=PT.scope_seconds(red, program["op_scopes"]))
+    for name in ("host_ms_per_step", "host_ms_per_step.mesh4",
+                 "im2col_ms_per_img", "pack_s", "warmup_s", "compile_s"):
+        assert spec.reader(name)(w) == pytest.approx(
+            PARENT[name.split(".")[0]], rel=1e-12), name
 
 
 def test_recorded_layers_cover_the_busy_time(program):
     red = tracered.reduce(program["trace"], [0])
-    lay = PT.layers(red, program["op_scopes"], program["meta"]["images"])
+    lay = PT.layers(PT.scope_seconds(red, program["op_scopes"]),
+                    program["meta"]["images"])
     per = lay["per_layer_ms_per_img"]
     assert sorted(per) == [f"layer{i:02d}" for i in range(13)]
     assert lay["unscoped_pct"] < 5.0
-    assert lay["unscoped_pct"] + sum(lay["scope_origin_pct"].values()) \
-        == pytest.approx(100.0)
-    assert lay["scope_origin_pct"] == pytest.approx(
-        program["meta"]["scope_origin_pct"], rel=1e-9)
+    origins = _origin_pct(red, program["op_scopes"])
+    assert lay["unscoped_pct"] + sum(origins.values()) == pytest.approx(
+        100.0)
+    assert origins == pytest.approx(PARENT["scope_origin_pct"], rel=1e-12)
     assert all(rec["walker"] > 0 and rec["im2col"] > 0
                for rec in per.values())
     total = sum(sum(rec.values()) for rec in per.values())
@@ -370,38 +513,118 @@ def test_recorded_gaps_are_named_by_the_program(program):
     assert "host: bench.step" in {n for n, _ in old.longest_gaps()}
 
 
-@pytest.mark.parametrize("cell_name", ["vgg16_224_closed8",
-                                       "alexnet_227_closed8"])
-def test_recorder_run_and_forward_scopes_on_the_cpu(cell_name):
-    """The harness's run at a small cut on the CPU with the recorder on,
-    as ``program_trace.py`` runs it (the device trace needs the chip):
-    the readers see set-up and every step, and the rebuilt forward's op
-    scopes cover every layer of the cut."""
+ALL = ["vgg16_224_closed8", "alexnet_227_closed8",
+       "vgg16_224_mesh4_closed32"]
+NEW = {"host_ms_per_step": ALL[:2],
+       "host_ms_per_step.mesh4": ALL[2:],
+       "im2col_ms_per_img": ALL[:2],
+       "pack_s": ALL, "warmup_s": ALL, "compile_s": ALL}
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_program_readers_read_nothing_untraced(name):
+    w = harness.Window(seconds=20.0, setup_s=30.0, slots=8, chips=1,
+                       images_in_window=100)
+    assert spec.reader(name)(w) is None
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_program_metrics_load_for_the_cells_they_list(name):
+    assert [c for c in ALL if name in {
+        m.name for m in spec.load_cell(c).per_layer}] == NEW[name]
+
+
+def test_an_untraced_run_leaves_the_recorder_off():
     from conftest import run_small, small_cell
     from repro import obs
+    obs.enable()
+    obs.disable()                # an empty store, recording off
+    out = run_small(small_cell("alexnet_227_closed8", layers=2, size=67,
+                               limit=5e-6))
+    assert out["correct"] and out["attempted"] > 0
+    assert obs.snapshot() == {"spans": []}
+    assert obs.span("a") is obs.span("b")        # the shared no-op
+
+
+def _fake_device_trace(monkeypatch):
+    """The CPU's profile has no device plane: give the harness one whose
+    operations are the served forward's instructions, run once inside
+    each ``serve.step`` that the recorder saw. Returns the forward texts
+    that the harness read its op scopes from."""
+    from repro import obs
+    texts = []
+    real = PT.op_scopes
+
+    def op_scopes(text):
+        texts.append(text)
+        return real(text)
+
+    def compact(_path):
+        names = list(real(texts[-1]))
+        events = []
+        for s in obs.snapshot()["spans"]:
+            if s["name"] == PT.STEP:
+                d = (s["end_ns"] - s["start_ns"]) / (len(names) + 1)
+                events += [[n, float(s["start_ns"] + i * d), d]
+                           for i, n in enumerate(names)]
+        return {"profile_start_ns": 0, "planes": [{
+            "name": "/device:TPU:0",
+            "lines": [{"name": "XLA Ops", "events": events}]}]}
+
+    monkeypatch.setattr(PT, "op_scopes", op_scopes)
+    monkeypatch.setattr(tracered, "compact", compact)
+    monkeypatch.setattr(tracered, "find_xplane", lambda log_dir: log_dir)
+    return texts
+
+
+@pytest.mark.parametrize("cell_name", ["vgg16_224_closed8",
+                                       "alexnet_227_closed8"])
+def test_recorder_run_and_forward_scopes_on_the_cpu(monkeypatch,
+                                                     cell_name):
+    """The harness's traced run at a small cut on the CPU, with only the
+    profiler's device plane made up (the CPU has none): the recorder is on
+    from set-up to the window's end and off after it, the readers see
+    set-up and every step, and the op scopes come from the served
+    forward's own compiled text and cover every layer of the cut."""
+    from conftest import small_cell
+    from repro import obs
+    texts = _fake_device_trace(monkeypatch)
     cell = small_cell(cell_name, layers=3, slots=4, size=67 if
                       cell_name.startswith("alexnet") else 32,
                       limit=spec.load_cell(cell_name).config["limits"][
                           "max_rel_err"])
-    seed = 2 ** 33 + 11
-    obs.enable()
-    try:
-        out = run_small(cell, seed)
-    finally:
-        obs.disable()
+    seen = []
+    out = harness.run(cell, 2 ** 33 + 11, 1.0, True,
+                      t_start=time.monotonic(), require_chip=False,
+                      on_window=seen.append)
     assert out["correct"]
-    snap = obs.snapshot()
-    every = (0, float("inf"))
-    first = min(s["start_ns"] for s in snap["spans"]
-                if s["name"] == PT.STEP)
-    assert PT.host_ms_per_step(snap, every) > 0
-    assert PT.images_in_window(snap, every) == out["attempted"]
-    assert PT.pack_s(snap, first) > 0 and PT.warmup_s(snap, first) > 0
-    assert PT.compile_s(snap, first) >= 0
-    ph = PT.step_phases(snap, every)
+    assert obs.span("a") is obs.span("b")        # the recorder is off
+    w = seen[0]
+    assert w.window_ns[0] < w.window_ns[1]
+    names = {s["name"] for s in w.program["spans"]}
+    assert {"setup.pack", "serve.verify", "serve.warmup", "jax.compile",
+            PT.STEP, *PT.STEP_PHASES} <= names
+    # the harness's count ends at ``seconds``, the span's with the last
+    # step of the window: they differ by one step's images at most
+    assert abs(PT.images_in_window(w.program, w.window_ns)
+               - w.images_in_window) <= w.slots < w.images_in_window
+    ph = PT.step_phases(w.program, w.window_ns)
     assert set(ph) == {PT.STEP, *PT.STEP_PHASES}
-    assert ph[PT.STEP]["n"] >= out["window_diag"]["steps"]
-    scopes = PT.op_scopes(PT.forward_hlo(cell, seed))
-    found = {s for s, _ in scopes.values() if s}
+    assert ph[PT.STEP]["n"] == out["window_diag"]["steps"]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    for name in ("host_ms_per_step", "pack_s", "warmup_s",
+                 "im2col_ms_per_img"):
+        assert got[name] > 0, name
+    assert got["compile_s"] >= 0
+    assert got["host_ms_per_step"] == PT.host_ms_per_step(w.program,
+                                                          w.window_ns)
+    # one forward text, the server's; its scopes cover every layer
+    assert len(texts) == 1
+    found = {s for s in w.scope_s if s}
     for layer in ("layer00", "layer01", "layer02"):
         assert {f"{layer}/walker", f"{layer}/im2col"} <= found, layer
+    # the idle gaps are named by the program's spans
+    assert out["breakdown"]["idle_gaps"]
+    assert {n for n, _ in out["breakdown"]["idle_gaps"]} <= {
+        f"host: {n}" for n in (PT.STEP, *PT.STEP_PHASES, "bench.step",
+                               "bench.collect", "no span")}
