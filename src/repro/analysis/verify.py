@@ -593,13 +593,20 @@ def verify_packed_conv(pc, path: str = "conv", *,
                         f"unknown layout {pc.layout!r}",
                         hint="layouts: 'channel' | 'tap'"))
         return out
-    if pc.layout == "tap" and pc.cin % bk != 0:
+    # unstructured full-patch layers may straddle taps; chunk pruning and
+    # lazy slabs need each K-chunk inside one tap
+    needs_whole_taps = ("the chunk pattern" if pc.pattern == "chunk" else
+                        "lazy im2col" if pc.tuned is not None
+                        and pc.tuned.config.im2col == "lazy" else None)
+    if pc.layout == "tap" and pc.cin % bk != 0 and needs_whole_taps:
         out.append(diag(
             "PC-LAYOUT", path,
-            f"tap layout with cin={pc.cin} % bk={bk} != 0 — a K-chunk "
-            f"would straddle filter taps",
-            hint="tap chunks must lie inside one tap (choose_chunk_layout "
-                 "falls back to channel layout otherwise)"))
+            f"tap layout with cin={pc.cin} % bk={bk} != 0 under "
+            f"{needs_whole_taps} — a K-chunk would straddle filter taps",
+            hint="chunk pruning and lazy slabs need each K-chunk inside one "
+                 "tap (choose_chunk_layout picks bk = cin or the channel "
+                 "layout; only unstructured full-patch layers may "
+                 "straddle)"))
         return out
 
     kh, kw, cin, cout = w.shape
@@ -823,8 +830,12 @@ def _verify_tuned(pc, path: str) -> List[Diagnostic]:
             f"tuned bn={cfg.bn} but the layer is packed at bn={bn_pack}",
             hint="autotune_conv(repack=True) re-packs at the winning bn "
                  "and drops the stale wl_cache — re-run it"))
-    legal = ("taps", "lazy", "auto") if pc.layout == "tap" \
-        else ("patches", "slices", "auto")
+    if pc.layout != "tap":
+        legal = ("patches", "slices", "auto")
+    elif pc.cin % bk:
+        legal = ("taps", "auto")          # chunks straddle taps: no slabs
+    else:
+        legal = ("taps", "lazy", "auto")
     if cfg.im2col not in legal:
         out.append(diag(
             "PC-TUNED", p,

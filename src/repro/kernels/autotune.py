@@ -114,8 +114,9 @@ def candidate_configs(conv: PackedConv, m_img: int, *,
     ``bm_rows``: the default grid block plus the whole-image block (one
     row block per image, 64-aligned — fewest steps). ``bn``: the
     pack-time width plus any chunk-compatible alternatives. ``im2col``:
-    the strategies legal for the layer's layout (``lazy`` only ever helps
-    when dead chunks exist, but it is scored, not assumed).
+    the strategies legal for the layer's layout (``lazy`` only where each
+    K-chunk lies inside one tap, and it only ever helps when dead chunks
+    exist, but it is scored, not assumed).
     """
     m_img = int(m_img)
     cout = conv.cout
@@ -127,8 +128,12 @@ def candidate_configs(conv: PackedConv, m_img: int, *,
     for cand in (64, bm.CHUNK):
         if cout % cand == 0:
             bns.add(cand)
-    strategies = (("taps", "lazy") if conv.layout == "tap"
-                  else ("patches", "slices"))
+    if conv.layout != "tap":
+        strategies = ("patches", "slices")
+    elif conv.cin % conv.packed.bk:
+        strategies = ("taps",)
+    else:
+        strategies = ("taps", "lazy")
     return [ConvTileConfig(bm_rows=bmr, bn=bnn, sub_m=8, im2col=s)
             for bmr in sorted(bms) for bnn in sorted(bns)
             for s in strategies]

@@ -273,8 +273,10 @@ def sparse_conv_spmm_wl(patches: jnp.ndarray, vals: jnp.ndarray,
 
 
 def _padded_input(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
-                  padding: Padding) -> Tuple[jnp.ndarray, int, int, int, int]:
-    """Zero-pad ``x`` for the conv window; returns (xp, oh, ow, sh, sw)."""
+                  padding: Padding, lanes: Tuple[int, int] = (0, 0)
+                  ) -> Tuple[jnp.ndarray, int, int, int, int]:
+    """Zero-pad ``x`` for the conv window, and by ``lanes`` (before, after)
+    on the channel axis; returns (xp, oh, ow, sh, sw)."""
     sh, sw = normalize_stride(stride)
     pad = normalize_padding(padding)
     b, H, W, cin = x.shape
@@ -282,7 +284,7 @@ def _padded_input(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
         pads = jax.lax.padtype_to_pads((H, W), (kh, kw), (sh, sw), pad)
     else:
         pads = pad
-    xp = jnp.pad(x, ((0, 0), tuple(pads[0]), tuple(pads[1]), (0, 0)))
+    xp = jnp.pad(x, ((0, 0), tuple(pads[0]), tuple(pads[1]), lanes))
     H2, W2 = xp.shape[1], xp.shape[2]
     oh = (H2 - kh) // sh + 1
     ow = (W2 - kw) // sw + 1
@@ -307,12 +309,14 @@ def conv_out_size(H: int, W: int, kh: int, kw: int, stride: Stride,
 
 def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
                     padding: Padding, *, strategy: str = "auto",
-                    m_pad: Optional[int] = None
+                    m_pad: Optional[int] = None, k_pad: int = 0
                     ) -> Tuple[jnp.ndarray, Tuple[int, int]]:
-    """im2col rows for the implicit GEMM: [B, M, Cin*kh*kw] (+ (OH, OW)).
+    """im2col rows for the implicit GEMM: [B, M, Cin*kh*kw + k_pad]
+    (+ (OH, OW)).
 
     ``M`` is ``OH*OW``, or ``m_pad`` when given: each image's rows then
-    end in zero rows up to ``m_pad`` (whole walker row blocks).
+    end in zero rows up to ``m_pad`` (whole walker row blocks). Each row
+    ends in ``k_pad`` zero columns (the packed filters' K pad).
 
     All strategies are pure jax ops, so patch extraction fuses into
     whatever jit the caller runs under — the K-fold patch blow-up never
@@ -332,11 +336,14 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
       packing (``w.reshape(kh*kw*cin, cout)``). Each slice is one block
       copy into its columns of the patch matrix: on the TPU the matrix is
       written once, with no one-hot convolution and no relayout copy.
-      Where ``m_pad`` adds rows, each slice is padded as it is copied,
-      which on the TPU replaces a separate pass over the whole matrix.
-      :func:`~repro.sparsity.conv.build_sparse_chain` packs tap-major
-      every layer whose ``cin`` fills whole chunks, so those layers take
-      this path.
+      Where ``cin`` is not whole lane tiles the slices are cut into
+      lane-tile blocks first (:func:`_tap_lane_blocks`), so every copy
+      stays tile-aligned. Where ``m_pad`` adds rows, each part is padded
+      as it is copied, and the ``k_pad`` columns are zeros of the same
+      concatenate: on the TPU neither costs a separate pass over the
+      whole matrix. :func:`~repro.sparsity.conv.build_sparse_chain` packs
+      tap-major every layer whose ``cin`` fills at least half a chunk, so
+      those layers take this path.
     * ``"auto"``    — patches on TPU, slices elsewhere (resolved at trace
       time, like the interpret/executor knobs); for channel layouts only,
       since :func:`sparse_conv2d_nhwc` resolves a tap layer to ``taps``.
@@ -352,6 +359,10 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             precision=jax.lax.Precision.HIGHEST)
         oh, ow = p.shape[1:3]
+    elif strategy == "taps" and cin % LANE:
+        parts = _tap_lane_blocks(x, kh, kw, stride, padding, k_pad)
+        oh, ow = parts[0].shape[1:3]
+        k_pad = 0                                 # inside the last block
     elif strategy in ("slices", "taps"):
         xp, oh, ow, sh, sw = _padded_input(x, kh, kw, stride, padding)
         parts = [xp[:, dy:dy + (oh - 1) * sh + 1:sh,
@@ -363,21 +374,66 @@ def extract_patches(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
     rows = (0, 0) if m_pad is None else (0, m_pad - m_img)
     if strategy == "taps":
         if rows[1]:
-            # each slice lands in its columns already row-padded
-            p = jnp.concatenate(
-                [jnp.pad(s.reshape(b, m_img, cin), ((0, 0), rows, (0, 0)))
-                 for s in parts], axis=-1)        # [b, m_pad, kh*kw*cin]
-        else:
+            # each part lands in its columns already row-padded
+            parts = [jnp.pad(s.reshape(b, m_img, s.shape[-1]),
+                             ((0, 0), rows, (0, 0))) for s in parts]
+        if k_pad:
+            parts.append(jnp.zeros(parts[0].shape[:-1] + (k_pad,), x.dtype))
+        p = jnp.concatenate(parts, axis=-1)   # [b, .., kh*kw*cin + k_pad]
+        if not rows[1]:
             # the barrier keeps XLA from sinking the reshape below the
             # concatenate, which would copy every strided slice out of the
             # map before copying it into the matrix
-            p = jax.lax.optimization_barrier(
-                jnp.concatenate(parts, axis=-1))  # [b, oh, ow, kh*kw*cin]
-        return p.reshape(b, m_img + rows[1], kh * kw * cin), (oh, ow)
+            p = jax.lax.optimization_barrier(p)
+        return p.reshape(b, m_img + rows[1], -1), (oh, ow)
     if strategy == "slices":
         p = jnp.stack(parts, axis=-1)             # [b, oh, ow, cin, kh*kw]
     p = p.reshape(b, m_img, cin * kh * kw)
-    return jnp.pad(p, ((0, 0), rows, (0, 0))), (oh, ow)
+    return jnp.pad(p, ((0, 0), rows, (0, k_pad))), (oh, ow)
+
+
+def _tap_lane_blocks(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
+                     padding: Padding, k_pad: int) -> list:
+    """The tap-major patch columns of a map whose ``cin`` is not whole
+    lane tiles, as ``LANE``-wide blocks ``[b, oh, ow, LANE]`` (the last
+    one narrower where the width is not whole tiles), ``k_pad`` zero
+    columns included.
+
+    Tap ``t`` owns columns ``[t * cin, (t + 1) * cin)``, which may start
+    inside a tile and straddle two. Its slice is read from a copy of the
+    map padded for the window and shifted ``t * cin % LANE`` lanes up the
+    channel axis, so each block is a tile-aligned window; where several
+    taps share a block, a lane select merges them (each window is zero
+    outside its tap's columns, so the merge is exact). On the TPU each
+    block fuses, pads included, into an in-place copy into the patch
+    matrix, as a whole-tile slice does; a plain concatenate of the
+    unaligned slices would first write every slice out on its own.
+    """
+    b, H, W, cin = x.shape
+    oh, ow = conv_out_size(H, W, kh, kw, stride, padding)
+    width = kh * kw * cin + k_pad
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, LANE), 3)
+    shifted = {}
+    blocks = []
+    for lo in range(0, width, LANE):
+        block = jnp.zeros((b, oh, ow, LANE), x.dtype)
+        for t in range(kh * kw):
+            s = t * cin
+            if s >= lo + LANE or s + cin <= lo:
+                continue
+            r = s % LANE
+            if r not in shifted:
+                shifted[r] = _padded_input(x, kh, kw, stride, padding,
+                                           lanes=(r, -(r + cin) % LANE))
+            xp, _, _, sh, sw = shifted[r]
+            dy, dx = divmod(t, kw)
+            c0 = lo - (s - r)             # the block, in the copy's lanes
+            piece = xp[:, dy:dy + (oh - 1) * sh + 1:sh,
+                       dx:dx + (ow - 1) * sw + 1:sw, c0:c0 + LANE]
+            block = jnp.where((lane >= s - lo) & (lane < s + cin - lo),
+                              piece, block)
+        blocks.append(block[..., :min(LANE, width - lo)])
+    return blocks
 
 
 def extract_tap_slabs(x: jnp.ndarray, kh: int, kw: int, stride: Stride,
@@ -451,8 +507,9 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
     executor) materializes only the live K-chunk slabs named by the
     pack-time work list instead of the full im2col matrix; combinations
     that need the full patch matrix (dense grid, activation compaction,
-    the Pallas walker) silently demote ``lazy`` to ``taps`` — slab
-    values equal patch values bitwise, so the result is unchanged.
+    the Pallas walker) or whose chunks straddle taps (``Cin % bk != 0``)
+    silently demote ``lazy`` to ``taps`` — slab values equal patch values
+    bitwise, so the result is unchanged.
 
     Returns ``(out, aux)`` where ``aux`` carries the optional
     ``occupancy`` (int32 [B, ceil(M_img/sub_m), n_blocks], padded rows
@@ -473,7 +530,8 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
         raise ValueError(f"im2col={im2col!r} needs layout='tap' packing")
     lazy = im2col == "lazy"
     if lazy and (schedule != "compact" or compact_activations
-                 or resolve_executor(executor) != "xla"):
+                 or resolve_executor(executor) != "xla"
+                 or x.shape[3] % w.bk):
         im2col, lazy = "taps", False
     b = x.shape[0]
     oh, ow = conv_out_size(x.shape[1], x.shape[2], kh, kw, stride, padding)
@@ -483,11 +541,11 @@ def sparse_conv2d_nhwc(x: jnp.ndarray, w: bm.BlockSparseMatrix, kh: int,
     flat = None
     if not lazy:
         with jax.named_scope("im2col"):
+            k_pad = k_total - kh * kw * x.shape[3]
+            assert k_pad >= 0, (x.shape, k_total)
             patches, _ = extract_patches(x, kh, kw, stride, padding,
-                                         strategy=im2col, m_pad=m_pad)
-            pad_k = k_total - patches.shape[-1]
-            assert pad_k >= 0, (patches.shape, k_total)
-            patches = jnp.pad(patches, ((0, 0), (0, 0), (0, pad_k)))
+                                         strategy=im2col, m_pad=m_pad,
+                                         k_pad=k_pad)
             flat = patches.reshape(b * m_pad, k_total)
     mb = (b * m_pad) // bm_rows
     aux = {"m_img": m_img, "k_total": k_total, "oh": oh, "ow": ow}

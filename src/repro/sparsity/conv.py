@@ -8,15 +8,17 @@ and reuses everything else:
 
 * **matrixization** — two layouts, chosen from the filter's shape.
   ``layout="tap"`` is the plain ``w.reshape(kh*kw*Cin, Cout)`` — K index
-  = tap * Cin + channel — so a K-chunk lies inside one filter tap and a
-  live chunk maps to one shifted-slab slice of the input. Every layer
-  whose ``Cin`` is a whole number of chunks takes it: its patch matrix is
-  then a block copy of the ``kh*kw`` shifted slices (no relayout), and
-  the lazy im2col path can materialize single chunks. ``layout="channel"``
-  is ``w.transpose(2, 0, 1, 3).reshape(Cin*kh*kw, Cout)``, matching
-  ``conv_general_dilated_patches`` feature order; narrower layers (a
-  3-channel stem, 64-channel VGG blocks) keep it, since a tap chunk would
-  straddle taps there. Both are chunk-padded for the BlockSpec grid.
+  = tap * Cin + channel. Every unstructured layer whose ``Cin`` fills at
+  least half a chunk takes it: its patch matrix is then one concatenate
+  of the ``kh*kw`` shifted slices and the K pad (no one-hot convolution,
+  no relayout). Where ``Cin`` is a whole number of chunks a K-chunk lies
+  inside one filter tap, so the lazy im2col path can materialize single
+  chunks; elsewhere (64- and 96-channel layers) chunks straddle taps,
+  which the walker does not mind and the lazy path cannot take.
+  ``layout="channel"`` is
+  ``w.transpose(2, 0, 1, 3).reshape(Cin*kh*kw, Cout)``, matching
+  ``conv_general_dilated_patches`` feature order; the 3-channel stems
+  keep it. Both are chunk-padded for the BlockSpec grid.
 * **chain folding** — greedy-balancing layer *i*'s output channels permutes
   the feature map's channel axis; the repair is folding the inverse into
   layer *i+1*'s **input-channel** axis (axis 2 of the 4-D filter), which is
@@ -47,12 +49,11 @@ def matrixize_filters(w: np.ndarray, chunk: int = bm.CHUNK,
 
     ``layout="channel"`` uses channel-major feature order (the
     ``conv_general_dilated_patches`` layout); ``layout="tap"`` keeps the
-    tensor's natural tap-major order (K = tap * Cin + c) and needs
-    ``Cin % bk == 0``, so each K-chunk lies inside one tap. The two are
-    the same matrix with its K rows permuted. :func:`build_sparse_chain`
-    picks ``tap`` for every layer that allows it (:func:`chain_layout`).
-    K pads to ``bk`` blocks and N to ``bn`` blocks (both default to
-    ``chunk``).
+    tensor's natural tap-major order (K = tap * Cin + c), for any ``Cin``:
+    a K-chunk lies inside one tap only where ``Cin % bk == 0``. The two
+    are the same matrix with its K rows permuted. :func:`chain_layout`
+    picks the layout. K pads to ``bk`` blocks and N to ``bn`` blocks
+    (both default to ``chunk``).
     """
     kh, kw, cin, cout = w.shape
     bk = chunk if bk is None else bk
@@ -61,9 +62,6 @@ def matrixize_filters(w: np.ndarray, chunk: int = bm.CHUNK,
         w_mat = np.asarray(w).transpose(2, 0, 1, 3).reshape(
             kh * kw * cin, cout)
     elif layout == "tap":
-        if cin % bk != 0:
-            raise ValueError(f"tap layout needs cin % bk == 0, got "
-                             f"cin={cin} bk={bk}")
         w_mat = np.asarray(w).reshape(kh * kw * cin, cout)
     else:
         raise ValueError(f"unknown layout {layout!r}")
@@ -91,11 +89,12 @@ def chain_layout(shape: Tuple[int, int, int, int], pattern: str,
 
     The chunk pattern picks its own (:func:`structured.
     choose_chunk_layout`). Unstructured layers are tap-major when ``Cin``
-    fills whole chunks, channel-major otherwise; both at ``bk = bn =
+    fills at least half a chunk (below a whole chunk their chunks
+    straddle taps), channel-major below that; both at ``bk = bn =
     chunk``. The choice rests on the shape alone."""
     if pattern == "chunk":
         return structured.choose_chunk_layout(shape, chunk)
-    return ("tap" if shape[2] % chunk == 0 else "channel"), chunk, chunk
+    return ("tap" if shape[2] >= chunk // 2 else "channel"), chunk, chunk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,9 +271,9 @@ def build_sparse_chain(weights: Sequence[np.ndarray], *, density: float = 1.0,
     violation — the pack-time gate for untrusted checkpoints.
 
     ``pattern="unstructured"`` (default): per-filter magnitude pruning and
-    per-channel greedy balance. A layer whose ``Cin`` is a whole number of
-    chunks packs tap-major, so its im2col rows are a block copy of the
-    ``kh*kw`` shifted slices of the map; narrower layers pack
+    per-channel greedy balance. A layer whose ``Cin`` fills at least half
+    a chunk packs tap-major, so its im2col rows are one concatenate of
+    the ``kh*kw`` shifted slices of the map; the 3-channel stems pack
     channel-major (:func:`chain_layout`). Pruning and balance act per
     output filter, so the layout only permutes the packed K rows.
     ``pattern="chunk"`` prunes at (bk, bn) tile granularity in the

@@ -181,11 +181,11 @@ def test_packing_is_a_span(recording):
     assert names.count("setup.pack") == 1
 
 
-@pytest.mark.parametrize("arch, tap_layers", [("VGGNet", 10),
-                                               ("AlexNet", 3)])
+@pytest.mark.parametrize("arch, tap_layers", [("VGGNet", 12),
+                                               ("AlexNet", 4)])
 def test_packing_span_counts_tap_major_layers(recording, arch, tap_layers):
-    """``tap_layers``: the layers whose input channels fill whole chunks
-    (VGG16 conv3_1 on, AlexNet conv3-5) are packed tap-major."""
+    """``tap_layers``: the layers whose input channels fill at least half
+    a chunk (VGG16 conv1_2 on, AlexNet conv2-5) are packed tap-major."""
     model = build_vision_model(arch, seed=0)
     pack, = [s for s in obs.snapshot()["spans"] if s["name"] == "setup.pack"]
     assert pack["attrs"] == {"tap_layers": tap_layers}

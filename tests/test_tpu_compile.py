@@ -141,34 +141,51 @@ def test_predicated_ffn_kernels_compile_at_qwen3_4b_width(one_chip, kernel):
     assert _mosaic_calls(compiled) == 1
 
 
-def test_tap_layer_im2col_is_block_copies_at_vgg16_conv3_1(one_chip):
-    """A tap-major layer's im2col (conv3_1: 112 px, cin 128, batch 8) is
-    block copies into the patch matrix: no one-hot convolution, and no
-    copy or transpose of the patch tensor before the walker reads it."""
+@pytest.mark.parametrize(
+    "hw, k, cin, cout, copies",
+    [(112, 3, 128, 128, 0), (224, 3, 64, 64, 0), (27, 5, 96, 256, 1)],
+    ids=["vgg16-conv3_1", "vgg16-conv1_2", "alexnet-conv2"])
+def test_tap_layer_im2col_is_block_copies_at_vgg16_conv3_1(
+        one_chip, hw, k, cin, cout, copies):
+    """A tap-major layer's im2col at batch 8 is block copies into the
+    patch matrix, K pad included: no one-hot convolution, and no pad,
+    transpose or copy of the patch tensor before the walker reads it.
+    conv3_1 (112 px, cin 128) copies whole-tile tap slices; conv1_2 (224
+    px, cin 64) and AlexNet conv2 (27 px, cin 96, 5x5, rows padded to
+    768) lane-tile blocks of taps that straddle chunks. At 27 px the
+    compiler writes the matrix batch-minor and adds one relayout copy
+    before the walker, as it does for every row-padded tap layer whose
+    width is not a multiple of 8. The input is the previous walker's
+    row-major map."""
     import math
     import re
+    from jax.experimental.layout import Format, Layout
     from repro.kernels.sparse_conv import sparse_conv2d_nhwc
     from repro.sparsity.conv import build_sparse_chain
     rng = np.random.default_rng(0)
     conv, = build_sparse_chain(
-        [rng.normal(size=(3, 3, 128, 128)).astype(np.float32)],
+        [rng.normal(size=(k, k, cin, cout)).astype(np.float32)],
         density=0.334)
     assert conv.layout == "tap"
     fn = jax.jit(lambda x: sparse_conv2d_nhwc(
-        x, conv.packed, 3, 3, 128, executor="pallas", interpret=False,
+        x, conv.packed, k, k, cout, executor="pallas", interpret=False,
         layout=conv.layout)[0])
-    compiled = fn.lower(_sds((8, 112, 112, 128), one_chip)).compile()
+    x = jax.ShapeDtypeStruct((8, hw, hw, cin), jnp.float32,
+                             sharding=Format(Layout((0, 1, 2, 3)), one_chip))
+    compiled = fn.lower(x).compile()
     assert _mosaic_calls(compiled) == 1
     text = compiled.as_text()
     ops = [(math.prod(int(d) for d in dims.split(",") if d), op)
            for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* (\S+)\(",
                                       text[text.index("\nENTRY"):])]
-    patch_elems = 8 * 112 * 112 * 9 * 128
+    m_pad = -(-hw * hw // 128) * 128
+    patch_elems = 8 * m_pad * conv.packed.shape[0]
+    assert conv.packed.shape[0] == k * k * cin + (-k * k * cin) % 128
     assert (patch_elems, "fusion") in ops
     assert "convolution" not in {op for _, op in ops}
     relayouts = [op for n, op in ops if n == patch_elems
-                 and op in ("copy", "copy-start", "transpose")]
-    assert not relayouts, relayouts
+                 and op in ("pad", "copy", "copy-start", "transpose")]
+    assert relayouts == ["copy"] * copies, relayouts
 
 
 @pytest.mark.parametrize("devices", [1, 4], ids=["one-chip", "data-mesh-4"])

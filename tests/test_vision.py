@@ -308,8 +308,35 @@ def test_taps_are_patches_in_tap_major_order(rng, stride, padding):
                                   np.asarray(a)[..., to_channel])
 
 
+@pytest.mark.parametrize("hw, k, cin, m_pad", [(12, 3, 64, None),
+                                                (27, 5, 96, 768)],
+                         ids=["cin64-3x3", "cin96-5x5-rows-padded"])
+def test_taps_fold_the_k_pad(rng, hw, k, cin, m_pad):
+    """Where ``cin`` is not whole lane tiles, ``taps`` with the K pad
+    folded in is ``patches`` with its columns moved to tap-major order,
+    its rows padded to ``m_pad`` and zero columns appended up to the next
+    chunk, bit for bit."""
+    x = rng.normal(size=(2, hw, hw, cin)).astype(np.float32)
+    taps = k * k
+    k_pad = (-taps * cin) % 128
+    a, hw_a = extract_patches(jnp.asarray(x), k, k, 1, "SAME",
+                              strategy="patches")
+    b, hw_b = extract_patches(jnp.asarray(x), k, k, 1, "SAME",
+                              strategy="taps", m_pad=m_pad, k_pad=k_pad)
+    assert hw_a == hw_b
+    to_channel = (np.arange(cin)[None, :] * taps
+                  + np.arange(taps)[:, None]).reshape(-1)
+    want = np.asarray(a)[..., to_channel]
+    rows = (m_pad or want.shape[1]) - want.shape[1]
+    want = np.pad(want, ((0, 0), (0, rows), (0, k_pad)))
+    assert b.shape == want.shape and b.shape[-1] % 128 == 0
+    np.testing.assert_array_equal(np.asarray(b).view(np.uint32),
+                                  want.view(np.uint32))
+
+
 # VGG16's conv2_1 -> conv3_1 widths: 64 input channels (half a chunk, so
-# channel-major), then 128 and 128 (whole chunks, so tap-major)
+# tap-major with chunks that straddle taps), then 128 and 128 (whole
+# chunks, so each chunk inside one tap)
 VGG_WIDTHS = [(3, 3, 64, 128), (3, 3, 128, 128), (3, 3, 128, 256)]
 
 
@@ -323,20 +350,25 @@ def vgg_width_chain():
 
 def test_unstructured_chain_is_tap_major_where_cin_fills_chunks(
         vgg_width_chain):
-    """Tap-major exactly where ``cin % chunk == 0``; the packed matrix is
-    the channel-major one with its K rows permuted, and the verifier
-    (``strict=True``, and ``deep`` here) accepts the chain."""
+    """Tap-major wherever ``cin`` fills at least half a chunk (64: chunks
+    straddle taps; 128: whole chunks); the packed matrix is the
+    channel-major one with its K rows permuted and the same zero K pad,
+    and the verifier (``strict=True``, and ``deep`` here) accepts the
+    chain."""
     from repro.analysis import has_errors, verify_chain
     from repro.sparsity.conv import matrixize_filters
-    assert [c.layout for c in vgg_width_chain] == ["channel", "tap", "tap"]
+    assert [c.layout for c in vgg_width_chain] == ["tap", "tap", "tap"]
     assert {c.pattern for c in vgg_width_chain} == {"unstructured"}
     assert not has_errors(verify_chain(vgg_width_chain, deep=True))
-    for c in vgg_width_chain[1:]:
+    for c in vgg_width_chain:
         tap = matrixize_filters(c.w_dense, layout="tap")
         chan = matrixize_filters(c.w_dense, layout="channel")
+        k = 9 * c.cin
         to_channel = (np.arange(c.cin)[None, :] * 9
                       + np.arange(9)[:, None]).reshape(-1)
-        np.testing.assert_array_equal(tap, chan[to_channel])
+        assert tap.shape == chan.shape and tap.shape[0] % 128 == 0
+        np.testing.assert_array_equal(tap[:k], chan[to_channel])
+        assert not tap[k:].any() and not chan[k:].any()
 
 
 @pytest.mark.parametrize("size", [16, 32])
@@ -368,6 +400,55 @@ def test_tap_major_chain_forward_matches_dense(vgg_width_chain, executor):
     assert out.shape == ref.shape == (2, 8, 8, 256)
     rel = float(jnp.abs(out - ref).max()) / (float(jnp.abs(ref).max()) + 1e-9)
     assert rel < 1e-4
+
+
+@pytest.mark.parametrize("layer", [0, 1], ids=["straddling", "whole"])
+def test_lazy_im2col_where_chunks_straddle_taps_is_taps(vgg_width_chain,
+                                                        layer):
+    """``im2col="lazy"`` gives the ``taps`` answer, bit for bit: on a
+    layer whose chunks straddle taps (cin 64) it is demoted to ``taps``,
+    since a slab is one tap's slice; on whole-chunk taps it runs."""
+    c = vgg_width_chain[layer]
+    assert c.layout == "tap" and (c.cin % c.packed.bk != 0) == (layer == 0)
+    rng = np.random.default_rng(5)
+    x = np.abs(rng.normal(size=(2, 12, 12, c.cin))).astype(np.float32)
+    x[rng.random(x.shape) >= 0.5] = 0.0
+    lazy, taps = (np.asarray(sparse_conv2d_nhwc(
+        jnp.asarray(x), c.packed, 3, 3, c.cout, executor="xla",
+        layout="tap", im2col=s)[0]) for s in ("lazy", "taps"))
+    np.testing.assert_array_equal(lazy, taps)
+
+
+@pytest.mark.parametrize("plant", ["chunk-pattern", "tuned-lazy"])
+def test_straddling_tap_chunks_refused_where_a_chunk_needs_one_tap(
+        vgg_width_chain, plant):
+    """An unstructured tap layer may straddle taps; under the chunk
+    pattern or a tuned ``lazy`` strategy the same layer reads
+    ``PC-LAYOUT``, and the tuner offers ``lazy`` only where each chunk
+    lies inside one tap."""
+    import dataclasses
+    from repro.analysis import Severity, verify_packed_conv
+    from repro.kernels.autotune import (ConvTileConfig, TuneRecord,
+                                        candidate_configs)
+
+    def rules(pc):
+        return {d.rule for d in verify_packed_conv(pc)
+                if d.severity >= Severity.ERROR}
+
+    c, whole = vgg_width_chain[0], vgg_width_chain[1]
+    assert c.cin % c.packed.bk and "PC-LAYOUT" not in rules(c)
+    assert {g.im2col for g in candidate_configs(c, 144)} == {"taps"}
+    assert {g.im2col for g in candidate_configs(whole, 144)} == \
+        {"taps", "lazy"}
+    if plant == "chunk-pattern":
+        bad = dataclasses.replace(c, pattern="chunk")
+    else:
+        rec = TuneRecord(config=ConvTileConfig(bm_rows=128, sub_m=8,
+                                               im2col="lazy"),
+                         cost=1.0, counts={}, table=[], m_img=1, batch=1,
+                         measured=False)
+        bad = dataclasses.replace(c, tuned=rec)
+    assert "PC-LAYOUT" in rules(bad)
 
 
 def test_compact_activations_rejected_under_jit(rng):
